@@ -50,6 +50,7 @@ import time
 
 from benchmarks.common import emit, save_json
 from benchmarks.fig9_resources import load_balance_mixes
+from repro.compile_cache import enable_compile_cache
 from repro.core.address_space import GlobalAddressSpace
 from repro.core.allocator import MemoryAllocator
 from repro.core.alloc_policies import POLICIES
@@ -194,6 +195,7 @@ def fig9_cells() -> list[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer events per cell (CI smoke)")

@@ -32,6 +32,7 @@ import numpy as np
 
 import repro.core.traces as T
 from benchmarks.common import save_json
+from repro.compile_cache import enable_compile_cache
 from repro.core import faults as flt
 from repro.core.emulator import DisaggregatedRack, ShardedRack
 from repro.core.types import NetworkConstants
@@ -150,6 +151,7 @@ def run_cell(name: str, trace, kw, schedule=None, constants=None) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small traces (the CI smoke configuration)")

@@ -76,6 +76,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 from repro.core import traces as T
 from repro.core.directory import CacheDirectory
 from repro.core.emulator import DisaggregatedRack
@@ -708,6 +709,7 @@ def bench_telemetry_overhead(quick: bool, repeats: int = 3) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small trace for CI smoke runs")

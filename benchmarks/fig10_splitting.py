@@ -6,6 +6,7 @@ from __future__ import annotations
 import time
 
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 from repro.core.emulator import run_workload
 
 
@@ -71,6 +72,7 @@ def sensitivity():
 
 
 def main() -> None:
+    enable_compile_cache()
     out = {"left": fixed_vs_adaptive(), "right": sensitivity()}
     save_json("fig10_splitting", out)
 

@@ -16,6 +16,7 @@ import time
 
 from benchmarks.common import (EngineChoice, emit, engine_from_argv,
                                save_json, run_workload_with_engine)
+from repro.compile_cache import enable_compile_cache
 
 ACCESSES = 500
 
@@ -87,6 +88,7 @@ def inter_blade(workloads=("TF", "GC", "M_A", "M_C"), blades=(1, 2, 4, 8),
 
 
 def main() -> None:
+    enable_compile_cache()
     choice = engine_from_argv()
     intra = intra_blade(engine=choice)
     inter = inter_blade(engine=choice)

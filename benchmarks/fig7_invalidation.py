@@ -7,9 +7,11 @@ import time
 
 from benchmarks.common import (emit, engine_from_argv, save_json,
                                run_workload_with_engine)
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     choice = engine_from_argv()
     rows = []
     for wl in ("TF", "GC", "M_A", "M_C"):

@@ -8,6 +8,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit, engine_from_argv, save_json
+from repro.compile_cache import enable_compile_cache
 from repro.core.cache import BladePageCache
 from repro.core.coherence import CoherenceEngine
 from repro.core.directory import CacheDirectory
@@ -98,6 +99,7 @@ def latency_breakdown(engine="scalar"):
 
 
 def main() -> None:
+    enable_compile_cache()
     choice = engine_from_argv()
     out = {
         "engine": choice.engine,

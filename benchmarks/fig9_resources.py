@@ -9,6 +9,7 @@ import time
 import numpy as np
 
 from benchmarks.common import emit, save_json
+from repro.compile_cache import enable_compile_cache
 from repro.core.address_space import GlobalAddressSpace
 from repro.core.allocator import MemoryAllocator
 from repro.core.emulator import run_workload
@@ -96,6 +97,7 @@ def load_balance():
 
 
 def main() -> None:
+    enable_compile_cache()
     out = {
         "left": directory_timeline(),
         "center": match_action_entries(),

@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig6_scaling",  # Fig. 6  intra/inter-blade scaling
     "fig7_invalidation",  # Fig. 7  invalidation overhead
@@ -27,6 +29,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--engine", choices=("scalar", "batched"),

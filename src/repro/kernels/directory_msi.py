@@ -148,7 +148,7 @@ def _msi_kernel(slots_ref, req_ref, write_ref, ttable_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def msi_transition(state, sharers, owner, slots, requesters, is_write,
-                   *, interpret: bool = True):
+                   *, interpret: bool):
     """Batched in-network MSI transitions (fused two-stage pipeline).
 
     Args mirror ref.msi_transition_ref.  The whole directory plus the

@@ -70,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 )
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """[B, H, S, D] blocked attention.  S must divide by block sizes (the
     caller pads); K/V may have fewer heads (GQA) — repeat before calling or
     pass Hkv == H."""

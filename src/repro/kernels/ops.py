@@ -1,8 +1,10 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults follow the runtime: on CPU (this container) the
-kernels execute in interpret mode; on TPU they compile to Mosaic.  All
-shapes are padded/validated here so kernel bodies stay branch-free.
+``interpret`` defaults follow the backend: on the CPU (the test suite,
+``JAX_PLATFORMS=cpu``) the kernels execute in interpret mode; on the TPU
+they compile to Mosaic.  Any other backend is refused rather than
+silently interpreted.  All shapes are padded/validated here so kernel
+bodies stay branch-free.
 """
 
 from __future__ import annotations
@@ -16,24 +18,39 @@ from repro.kernels import range_match as _rm
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels lower to Mosaic on 'tpu' and run interpreted on "
+        f"'cpu'; backend {backend!r} has neither (set JAX_PLATFORMS=cpu "
+        f"or run on a TPU)")
 
 
-def translate_lookup(vaddrs, table, **kw):
+def _tcam_kw(kw: dict) -> dict:
     kw.setdefault("interpret", _default_interpret())
     if kw["interpret"]:
         # Interpret mode pays Python-level cost per grid step: use a
         # large request block so big batches run in a handful of steps
-        # (on TPU the default 256 keeps the match matrix in VREGs).
+        # (on TPU the default BLOCK_B matches XLA's 1-D operand tiling).
         kw.setdefault("block_b", 8192)
-    return _rm.translate_lookup(vaddrs, table, **kw)
+    return kw
+
+
+def translate_lookup(vaddrs, table, **kw):
+    return _rm.translate_lookup(vaddrs, table, **_tcam_kw(kw))
 
 
 def protect_check(pdids, vaddrs, need, table, **kw):
-    kw.setdefault("interpret", _default_interpret())
-    if kw["interpret"]:
-        kw.setdefault("block_b", 8192)
-    return _rm.protect_check(pdids, vaddrs, need, table, **kw)
+    return _rm.protect_check(pdids, vaddrs, need, table, **_tcam_kw(kw))
+
+
+def lower_tcam(batch: int, rows: int, **kw) -> dict:
+    """The TCAM programs exactly as the two wrappers above would run them
+    (see :func:`range_match.lower_tcam`)."""
+    return _rm.lower_tcam(batch, rows, **_tcam_kw(kw))
 
 
 def msi_transition(state, sharers, owner, slots, requesters, is_write, **kw):

@@ -96,7 +96,7 @@ def _paged_attn_kernel(
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(q, kv_pages_k, kv_pages_v, block_tables, seq_lens, *,
-                    scale: float | None = None, interpret: bool = True):
+                    interpret: bool, scale: float | None = None):
     """Decode attention over the paged pool.
 
     Args:

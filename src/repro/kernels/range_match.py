@@ -4,8 +4,13 @@ The switch matches each access's (PDID, vaddr) against power-of-two range
 entries *in parallel* and takes the longest-prefix match.  On TPU the
 match-action table lives in VMEM (the SRAM/TCAM analogue) and a batch of
 access descriptors is matched per invocation: a [block_b, T] comparison
-matrix is materialized in VREGs and reduced with a masked argmin over
-prefix lengths (LPM semantics).
+matrix is materialized in VMEM and reduced with a masked min over
+(prefix length, row) keys (LPM semantics).
+
+Request vectors are 1-D, and XLA tiles a 1-D int32 array on the TPU in
+runs of 1024 elements, so a request block must be a multiple of
+``BLOCK_B`` for Mosaic to accept the operand layout; the wrappers pad
+the batch to the block.
 
 64-bit virtual addresses are carried as (hi, lo) int32 pairs because the
 TPU vector unit is 32-bit and JAX runs with x64 disabled; ``split64_np``
@@ -27,6 +32,7 @@ from jax.experimental import pallas as pl
 
 NO_MATCH = 0x7FFFFFFF
 _LANES = 128
+BLOCK_B = 1024  # XLA's 1-D int32 tile on the TPU; smaller blocks are refused
 _LPM_STRIDE = 1 << 20  # > max table rows; makes (log2, row) keys unique
 
 
@@ -75,12 +81,18 @@ def _translate_kernel(vhi_ref, vlo_ref, tbl_hi_ref, tbl_lo_ref, tbl_log2_ref,
                    log2[None, :])
     m = jnp.logical_and(m, valid)
     # LPM: smallest log2 wins; row index breaks ties deterministically.
+    # The key packs (log2, row) so its min names the winning row, and a
+    # one-hot mask on the min recovers the row's blade with a masked sum
+    # (Mosaic lowers int32 min/sum lane reductions, not argmin/gather).
     big = jnp.int32(1 << 30)
     key = jnp.where(m, log2[None, :] * jnp.int32(_LPM_STRIDE) + t_idx, big)
-    best = jnp.argmin(key, axis=1).astype(jnp.int32)
-    matched = jnp.min(key, axis=1) < big
-    blade_ref[:] = jnp.where(matched, blade[best], jnp.int32(-1))
-    idx_ref[:] = jnp.where(matched, best, jnp.int32(NO_MATCH))
+    kmin = jnp.min(key, axis=1)
+    matched = kmin < big
+    hit = key == kmin[:, None]
+    best_blade = jnp.sum(jnp.where(hit, blade[None, :], 0), axis=1)
+    blade_ref[:] = jnp.where(matched, best_blade, jnp.int32(-1))
+    idx_ref[:] = jnp.where(matched, kmin & jnp.int32(_LPM_STRIDE - 1),
+                           jnp.int32(NO_MATCH))
 
 
 def _protect_kernel(pdid_ref, vhi_ref, vlo_ref, need_ref, tbl_pdid_ref,
@@ -149,7 +161,8 @@ def _translate_call(vhi, vlo, bhi, blo, log2, blade, nrows, *, block_b, interpre
     )(vhi, vlo, bhi, blo, log2, blade, nrows)
 
 
-def translate_lookup(vaddrs, table, *, block_b: int = 256, interpret: bool = True):
+def translate_lookup(vaddrs, table, *, interpret: bool,
+                     block_b: int = BLOCK_B):
     """Batch-translate virtual addresses.
 
     Args:
@@ -202,8 +215,8 @@ def _protect_call(pdids, vhi, vlo, need, t_pdid, bhi, blo, log2, perm, nrows,
     )(pdids, vhi, vlo, need, t_pdid, bhi, blo, log2, perm, nrows)
 
 
-def protect_check(pdids, vaddrs, need, table, *, block_b: int = 256,
-                  interpret: bool = True):
+def protect_check(pdids, vaddrs, need, table, *, interpret: bool,
+                  block_b: int = BLOCK_B):
     """Batch protection check.
 
     Args:
@@ -231,3 +244,24 @@ def protect_check(pdids, vaddrs, need, table, *, block_b: int = 256,
     allow = _protect_call(pdids, vhi, vlo, need, t_pdid, bhi, blo, log2, perm,
                           nrows, block_b=block_b, interpret=interpret)
     return np.asarray(allow[:b])
+
+
+def lower_tcam(batch: int, rows: int, *, interpret: bool,
+               block_b: int = BLOCK_B, sharding=None) -> dict:
+    """Both TCAM programs as the wrappers above call them for ``batch``
+    requests against a ``rows``-row table: lowered, not compiled.
+    ``sharding`` places the operands, e.g. on a described chip for an
+    ahead-of-time compile."""
+    b = batch + (-batch) % block_b
+    t = rows + (-rows) % _LANES
+
+    def vec(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)
+
+    kw = dict(block_b=block_b, interpret=interpret)
+    return {
+        "translate_lookup": _translate_call.lower(
+            vec(b), vec(b), *[vec(t)] * 4, vec(1), **kw),
+        "protect_check": _protect_call.lower(
+            *[vec(b)] * 4, *[vec(t)] * 5, vec(1), **kw),
+    }

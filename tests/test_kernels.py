@@ -6,8 +6,30 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops as K
+from repro.kernels import range_match
 from repro.kernels import ref as R
 from repro.kernels.directory_msi import build_transition_table
+
+
+# ------------------------------------------------------------------ #
+# interpret mode: chosen by backend, never by default
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_default_interpret_follows_backend(backend, interpret, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert K._default_interpret() is interpret
+
+
+def test_default_interpret_refuses_other_backends(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        K._default_interpret()
+
+
+def test_raw_tcam_entry_points_require_interpret():
+    v = np.zeros(4, np.int64)
+    with pytest.raises(TypeError, match="interpret"):
+        range_match.translate_lookup(v, _toy_translate_table())
 
 
 # ------------------------------------------------------------------ #
