@@ -15,6 +15,7 @@ from dataclasses import dataclass, field
 from repro.core.allocator import MemoryAllocator
 from repro.core.bounded_splitting import BoundedSplitting, EpochReport
 from repro.core.coherence import CoherenceEngine
+from repro.core.spans import span
 from repro.core.switch import InNetworkMMU
 from repro.core.types import VMA, MSIState, Perm
 from repro.telemetry import events as tev
@@ -76,11 +77,14 @@ class ControlPlane:
     # ------------------------------------------------------------------ #
     def sys_mmap(self, pdid: int, length: int, perm: Perm = Perm.RW,
                  requesting_blade: int | None = None) -> SyscallResult:
-        vma = self.allocator.mmap(pdid, length, perm)
-        self.mmu.protection.grant_vma(vma)
+        with span("arena_alloc"):
+            vma = self.allocator.mmap(pdid, length, perm)
+            self.mmu.protection.grant_vma(vma)
         if requesting_blade is not None and self.prepopulate_on_mmap:
             # §4.4 pre-population: allocating blade gets exclusive access.
-            self.mmu.engine.prepopulate(vma.base, vma.length, requesting_blade)
+            with span("arena_prepopulate"):
+                self.mmu.engine.prepopulate(vma.base, vma.length,
+                                            requesting_blade)
         return SyscallResult(retval=vma.base, vma=vma)
 
     def sys_munmap(self, pdid: int, base: int) -> SyscallResult:

@@ -33,6 +33,7 @@ import numpy as np
 from repro.core import faults as flt
 from repro.core.control_plane import ControlPlane
 from repro.core.network_model import NetworkModel
+from repro.core.spans import spanned
 from repro.core.switch import InNetworkMMU, ShardMap, make_mmu
 from repro.core.systems import SYSTEMS, make_model
 from repro.core.traces import Trace
@@ -65,6 +66,14 @@ class EmulationResult:
     # pre-passes / scheduling / device replay / latency reconstruction /
     # epoch control — the per-phase perf trajectory BENCH_*.json tracks.
     phase_times: dict = field(default_factory=dict)
+    # Work counts of the batched engine's device calls (empty for the
+    # scalar engine): ``waves`` (the wave loop's trip count, summed over
+    # calls), ``wave_slots`` (lanes x waves), ``packets`` (accesses plus
+    # injected eviction packets), and ``h2d_bytes`` / ``d2h_bytes`` (the
+    # operands shipped to, and results read back from, the wave loop and
+    # both TCAM kernels).  Discarded speculative chunks count: the work
+    # was done.  See docs/OBSERVABILITY.md.
+    counters: dict = field(default_factory=dict)
     # Multi-switch (sharded-directory) racks: how many switch shards the
     # directory was partitioned across, the per-shard access counts
     # (accesses homed at each shard, faults included), and how many
@@ -138,6 +147,7 @@ class EmulationResult:
 class DisaggregatedRack:
     """One emulated rack: N compute blades x M memory blades + switch."""
 
+    @spanned("rack_build")
     def __init__(
         self,
         system: str = "mind",

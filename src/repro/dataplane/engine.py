@@ -112,6 +112,7 @@ hop in the host latency reconstruction, mirroring the scalar
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 
@@ -120,6 +121,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import faults as flt
+from repro.core import spans
 from repro.core.types import PAGE_SHIFT, MSIState, next_pow2
 from repro.dataplane.scheduler import build_wave_schedule, partition_by_shard
 from repro.dataplane.tables import (
@@ -134,12 +136,17 @@ from repro.telemetry import events as tev
 _KINDS = ("I->S", "I->M", "S->S", "S->M", "M->M", "M->S")
 
 #: The frozen ``phase_times`` key schema.  Every run() populates exactly
-#: these keys; benchmarks/dataplane_bench.py and docs/BENCHMARKS.md key
-#: off this tuple, so additions/renames happen here and nowhere else.
+#: these keys; benchmarks/dataplane_bench.py, docs/BENCHMARKS.md and the
+#: chip benchmark's metric readers (benchmarks/chip/metrics/) key off
+#: this tuple, so additions/renames happen here and nowhere else.  Each
+#: phase but ``speculation_overhead`` is also the span ``mind.<phase>``.
 PHASES = (
     "arena_setup", "state_build", "stage12_tcam", "residency_prepass",
     "cache_prepass", "schedule", "device", "merge_writeback",
     "latency_reconstruct", "epoch_control", "speculation_overhead")
+
+#: The keys of ``EmulationResult.counters`` a run() fills.
+COUNTERS = ("waves", "wave_slots", "packets", "h2d_bytes", "d2h_bytes")
 
 
 # --------------------------------------------------------------------- #
@@ -316,6 +323,7 @@ def _popcount32(a: np.ndarray) -> int:
 class BatchedDataPlane:
     """Batched replay engine bound to one DisaggregatedRack."""
 
+    @spans.spanned("rack_build")
     def __init__(self, rack, chunk_size: int = 65536,
                  lanes: int | None = None):
         # The packed int32 kernel output words bound the configuration:
@@ -346,9 +354,11 @@ class BatchedDataPlane:
         # M->S downgrades keep a read-only copy at the old owner; the
         # kernel and both pre-passes model it, so no refusal needed.
         self._dkc = bool(rack.mmu.engine.downgrade_keeps_copy)
-        # Wall-clock per engine phase of the last run() — the perf
-        # trajectory benchmarks persist into BENCH_*.json.
+        # Wall-clock per engine phase of the last run(), and its device
+        # calls' work counts: what benchmarks/dataplane_bench.py persists
+        # into BENCH_*.json and benchmarks/chip/metrics/ reads.
         self.phase_times: dict[str, float] = {}
+        self.counters: dict[str, int] = {}
         self._rt = None  # sorted RegionTable cache (fast-path lookup)
         # Persistent device table for the capacity-pressure regime:
         # unsorted rows (live + evicted) keyed by `keys`/`_row_of`, kept
@@ -369,51 +379,58 @@ class BatchedDataPlane:
 
     # ------------------------------------------------------------------ #
     def run(self, trace, max_accesses: int | None = None):
+        """Replay ``trace`` (its first ``max_accesses``) inside the span
+        ``mind.run``, whose metadata names the trace and its length."""
+        n = len(trace) if max_accesses is None else min(len(trace), max_accesses)
+        with spans.span("run", trace=trace.name, accesses=n):
+            return self._run(trace, n)
+
+    def _run(self, trace, n: int):
         from repro.core.emulator import EmulationResult
 
         rack = self.rack
         self.phase_times = {k: 0.0 for k in PHASES}
         pt = self.phase_times
-        t0 = time.perf_counter()
-        # Arena mapping happens with the directory hooks attached (as in
-        # the scalar engine), so mmap-time install/evict events match;
-        # everything after reconstructs events host-side instead.
-        segs = rack._map_arena(trace)
-        self._tel = getattr(rack, "telemetry", None)
-        t0 = self._tick("arena_setup", t0)
-        n = len(trace) if max_accesses is None else min(len(trace), max_accesses)
+        self.counters = dict.fromkeys(COUNTERS, 0)
+        with self._phase("arena_setup"):
+            # Arena mapping happens with the directory hooks attached (as
+            # in the scalar engine), so mmap-time install/evict events
+            # match; everything after reconstructs events host-side.
+            segs = rack._map_arena(trace)
+            self._tel = getattr(rack, "telemetry", None)
         nthreads = rack.nb * rack.tpb
         mmu = rack.mmu
         knet = mmu.network.k
         pso = rack.model.pso
 
-        threads = (trace.threads[:n].astype(np.int64) % nthreads).astype(np.int32)
-        blades = (threads // rack.tpb).astype(np.int32)
-        writes = trace.ops[:n].astype(np.int32)
-        vaddrs = (rack._to_vaddr_batch(segs, trace.offsets[:n])
-                  if n else np.zeros(0, np.int64))
+        with self._phase("state_build"):
+            threads = (trace.threads[:n].astype(np.int64)
+                       % nthreads).astype(np.int32)
+            blades = (threads // rack.tpb).astype(np.int32)
+            writes = trace.ops[:n].astype(np.int32)
+            vaddrs = (rack._to_vaddr_batch(segs, trace.offsets[:n])
+                      if n else np.zeros(0, np.int64))
 
-        state = build_dataplane_state(mmu, segs, rack.nb,
-                                      shard_map=self._smap)
-        self.state = state
-        self._rt = state.regions
-        self._dtab = None  # mapping may have grown since a prior run
-        self._row_of = {}
-        dense = state.page_map.dense_of(vaddrs)
-        self._plan_cache_replay(blades, dense, state)
-        # Home-switch routing: the shard each access's region is homed
-        # at, and whether it enters the rack at a different switch (the
-        # accesses that pay the switch-to-switch hop unless they turn
-        # out to be pure local hits).
-        self._cross_acc = 0
-        if self._sharded:
-            home_acc = self._smap.home_of_batch(vaddrs)
-            ingress_acc = self._smap.ingress_of_batch(blades)
-            cross_acc = home_acc != ingress_acc
-        else:
-            home_acc = np.zeros(n, np.int32)
-            cross_acc = np.zeros(n, bool)
-        t0 = self._tick("state_build", t0)
+            state = build_dataplane_state(mmu, segs, rack.nb,
+                                          shard_map=self._smap)
+            self.state = state
+            self._rt = state.regions
+            self._dtab = None  # mapping may have grown since a prior run
+            self._row_of = {}
+            dense = state.page_map.dense_of(vaddrs)
+            self._plan_cache_replay(blades, dense, state)
+            # Home-switch routing: the shard each access's region is
+            # homed at, and whether it enters the rack at a different
+            # switch (the accesses that pay the switch-to-switch hop
+            # unless they turn out to be pure local hits).
+            self._cross_acc = 0
+            if self._sharded:
+                home_acc = self._smap.home_of_batch(vaddrs)
+                ingress_acc = self._smap.ingress_of_batch(blades)
+                cross_acc = home_acc != ingress_acc
+            else:
+                home_acc = np.zeros(n, np.int32)
+                cross_acc = np.zeros(n, bool)
 
         # Pipeline stages 1+2 over the whole trace: the Pallas TCAM
         # kernels (protection in parallel with translation, §3.2).  On a
@@ -422,34 +439,42 @@ class BatchedDataPlane:
         # at its *home* pipeline (the tables are control-plane replicas,
         # so the split changes where the work runs, never the result).
         faults = np.zeros(n, bool)
-        if n:
-            from repro.kernels import ops as K
-            from repro.kernels.range_match import NO_MATCH
+        with self._phase("stage12_tcam"):
+            if n:
+                from repro.kernels import ops as K
+                from repro.kernels.range_match import NO_MATCH
 
-            need = np.where(writes == 1, 2, 1).astype(np.int32)
-            if self._sharded:
-                allow = np.ones(n, bool)
-                rows = np.full(n, NO_MATCH, np.int64)
-                for s in range(self._nshards):
-                    isel = np.flatnonzero(ingress_acc == s)
-                    if len(isel):
-                        allow[isel] = np.asarray(K.protect_check(
-                            np.ones(len(isel), np.int32), vaddrs[isel],
-                            need[isel], state.protect))
-                    hsel = np.flatnonzero(home_acc == s)
-                    if len(hsel):
-                        _, r = K.translate_lookup(vaddrs[hsel],
-                                                  state.translate)
-                        rows[hsel] = np.asarray(r)
-            else:
-                allow = K.protect_check(
-                    np.ones(n, np.int32), vaddrs, need, state.protect)
-                _, rows = K.translate_lookup(vaddrs, state.translate)
-            if (np.asarray(rows) == NO_MATCH).any():
-                raise UnsupportedByBatchedEngine(
-                    "trace touches vaddrs outside every blade range")
-            faults = ~np.asarray(allow)
-        t0 = self._tick("stage12_tcam", t0)
+                def protect(va, nd):
+                    args = (np.ones(len(va), np.int32), va, nd,
+                            state.protect)
+                    allow = np.asarray(K.protect_check(*args))
+                    self._count_transfer(args, (allow,))
+                    return allow
+
+                def translate(va):
+                    args = (va, state.translate)
+                    out = tuple(map(np.asarray, K.translate_lookup(*args)))
+                    self._count_transfer(args, out)
+                    return out[1]
+
+                need = np.where(writes == 1, 2, 1).astype(np.int32)
+                if self._sharded:
+                    allow = np.ones(n, bool)
+                    rows = np.full(n, NO_MATCH, np.int64)
+                    for s in range(self._nshards):
+                        isel = np.flatnonzero(ingress_acc == s)
+                        if len(isel):
+                            allow[isel] = protect(vaddrs[isel], need[isel])
+                        hsel = np.flatnonzero(home_acc == s)
+                        if len(hsel):
+                            rows[hsel] = translate(vaddrs[hsel])
+                else:
+                    allow = protect(vaddrs, need)
+                    rows = translate(vaddrs)
+                if (rows == NO_MATCH).any():
+                    raise UnsupportedByBatchedEngine(
+                        "trace touches vaddrs outside every blade range")
+                faults = ~allow
 
         keep = ~faults
         if n and keep.any():
@@ -527,7 +552,7 @@ class BatchedDataPlane:
             disc = chg_a * chg_a + 2.0 * chg_g * gap
             return int((math.sqrt(disc) - chg_a) / chg_g)
 
-        def span(lo, hi):
+        def replay(lo, hi):
             m = keep[lo:hi]
             if not m.any():
                 return np.zeros(0, np.int64), np.zeros(0, np.float64)
@@ -539,7 +564,7 @@ class BatchedDataPlane:
             note_avg(charged)
             return np.flatnonzero(m), charged
 
-        def span_defer(lo, hi):
+        def replay_defer(lo, hi):
             m = keep[lo:hi]
             if not m.any():
                 return (np.zeros(0, np.int64), np.zeros(0, np.float64),
@@ -576,26 +601,27 @@ class BatchedDataPlane:
             sched = rack._fault_schedule
             while sched and sched[0].index == lo:
                 fev = sched.pop(0)
-                if self._tel is not None:
-                    self._tel.cur_index = lo
-                wp = (flt.written_page_prefix(vaddrs, writes, lo)
-                      if fev.kind == flt.BLADE_KILL else None)
-                rack._fire_fault(fev, written_pages=wp)
-                if fev.kind == flt.SWITCH_KILL:
-                    self._rt = None
-                    self._dtab = None
-                    self._row_of = {}
+                with spans.span("fault", kind=fev.kind):
+                    if self._tel is not None:
+                        self._tel.cur_index = lo
+                    wp = (flt.written_page_prefix(vaddrs, writes, lo)
+                          if fev.kind == flt.BLADE_KILL else None)
+                    rack._fire_fault(fev, written_pages=wp)
+                    if fev.kind == flt.SWITCH_KILL:
+                        self._rt = None
+                        self._dtab = None
+                        self._row_of = {}
             if sched:
                 full = min(full, sched[0].index - lo)
             safe = (self._next_chunk_size(clocks, next_epoch_at, inflight)
                     if rack.epoch_driver_enabled else full)
             if safe >= full:
-                span(lo, lo + full)
+                replay(lo, lo + full)
                 hi = lo + full
             elif safe <= 1:
                 # At the boundary itself: one access, exactly like the
                 # scalar per-access check.
-                span(lo, lo + 1)
+                replay(lo, lo + 1)
                 hi = lo + 1
             else:
                 # Speculate-and-truncate (ISSUE 4): the worst-case bound
@@ -610,67 +636,77 @@ class BatchedDataPlane:
                 if last_epoch_len:
                     est = max(est, last_epoch_len - since_epoch)
                 spec = min(full, max(int(0.95 * est), 64))
-                ts = time.perf_counter()
-                pt_before = dict(pt)
+                # One span per attempt; a discarded one holds the span
+                # `mind.spec_rollback`, and the exact pre-boundary prefix
+                # replays after it.
+                with spans.span("speculate", index=lo, size=spec):
+                    ts = time.perf_counter()
+                    pt_before = dict(pt)
 
-                def discard_phases():
-                    # A discarded speculative replay is pure speculation
-                    # overhead: undo its per-phase attribution so the
-                    # phases trajectory reports the waste where it
-                    # belongs.
-                    waste = time.perf_counter() - ts
-                    for k, v in pt_before.items():
-                        pt[k] = v
-                    pt["speculation_overhead"] += waste
+                    def discard_phases():
+                        # A discarded speculative replay is pure
+                        # speculation overhead: undo its per-phase
+                        # attribution so the phases trajectory reports
+                        # the waste where it belongs.
+                        waste = time.perf_counter() - ts
+                        for k, v in pt_before.items():
+                            pt[k] = v
+                        pt["speculation_overhead"] += waste
 
-                res = (span_defer(lo, lo + spec)
-                       if self._cache_shadows is None else None)
-                if res is not None:
-                    # Fast-path chunk: all host effects are deferred in
-                    # `commit`, so mis-speculation just discards it.
-                    kept, charged, commit = res
-                    cross = self._exact_crossing(
-                        clocks, threads[lo:lo + spec], kept, charged,
-                        next_epoch_at)
-                    if cross is None or cross == spec - 1:
-                        commit()
-                        note_avg(charged)
-                        hi = lo + spec
+                    res = (replay_defer(lo, lo + spec)
+                           if self._cache_shadows is None else None)
+                    if res is not None:
+                        # Fast-path chunk: all host effects are deferred
+                        # in `commit`, so mis-speculation just discards it.
+                        kept, charged, commit = res
+                        cross = self._exact_crossing(
+                            clocks, threads[lo:lo + spec], kept, charged,
+                            next_epoch_at)
+                        if cross is None or cross == spec - 1:
+                            with spans.span("spec_commit"):
+                                commit()
+                            note_avg(charged)
+                            hi = lo + spec
+                        else:
+                            with spans.span("spec_rollback"):
+                                if self._tel is not None:
+                                    # Discarded commit closure: no events
+                                    # were emitted, only the rollback
+                                    # itself is noted.
+                                    self._tel.event(tev.SPEC_ROLLBACK,
+                                                    index=lo + cross,
+                                                    pages=spec - (cross + 1))
+                                discard_phases()
+                            hi = lo + cross + 1
                     else:
-                        discard_phases()
-                        if self._tel is not None:
-                            # Discarded commit closure: no events were
-                            # emitted, only the rollback itself is noted.
-                            self._tel.event(tev.SPEC_ROLLBACK,
-                                            index=lo + cross,
-                                            pages=spec - (cross + 1))
-                        hi = lo + cross + 1
-                        span(lo, hi)  # the exact pre-boundary prefix
-                else:
-                    # Installs / capacity pressure / cache shadows mutate
-                    # state mid-chunk: speculate under a full snapshot.
-                    t1 = time.perf_counter()
-                    snap = self._snapshot(clocks, inflight, breakdown,
-                                          trans_lat)
-                    pt["speculation_overhead"] += time.perf_counter() - t1
-                    kept, charged = span(lo, lo + spec)
-                    cross = self._exact_crossing(
-                        snap["clocks"], threads[lo:lo + spec], kept, charged,
-                        next_epoch_at)
-                    if cross is None or cross == spec - 1:
-                        hi = lo + spec
-                    else:
-                        self._rollback(snap, clocks, inflight, breakdown,
-                                       trans_lat)
-                        discard_phases()
-                        if self._tel is not None:
-                            # After the rollback, so the marker survives
-                            # the event-ring truncation it triggered.
-                            self._tel.event(tev.SPEC_ROLLBACK,
-                                            index=lo + cross,
-                                            pages=spec - (cross + 1))
-                        hi = lo + cross + 1
-                        span(lo, hi)  # the exact pre-boundary prefix
+                        # Installs / capacity pressure / cache shadows
+                        # mutate state mid-chunk: speculate under a full
+                        # snapshot.
+                        t1 = time.perf_counter()
+                        snap = self._snapshot(clocks, inflight, breakdown,
+                                              trans_lat)
+                        pt["speculation_overhead"] += time.perf_counter() - t1
+                        kept, charged = replay(lo, lo + spec)
+                        cross = self._exact_crossing(
+                            snap["clocks"], threads[lo:lo + spec], kept,
+                            charged, next_epoch_at)
+                        if cross is None or cross == spec - 1:
+                            hi = lo + spec
+                        else:
+                            with spans.span("spec_rollback"):
+                                self._rollback(snap, clocks, inflight,
+                                               breakdown, trans_lat)
+                                if self._tel is not None:
+                                    # After the rollback, so the marker
+                                    # survives the event-ring truncation
+                                    # it triggered.
+                                    self._tel.event(tev.SPEC_ROLLBACK,
+                                                    index=lo + cross,
+                                                    pages=spec - (cross + 1))
+                                discard_phases()
+                            hi = lo + cross + 1
+                if hi < lo + spec:
+                    replay(lo, hi)  # the exact pre-boundary prefix
             since_epoch += hi - lo
             # One boundary per check, like the scalar per-access `if` —
             # the exact chunk sizing guarantees the crossing access ended
@@ -678,85 +714,88 @@ class BatchedDataPlane:
             if (rack.epoch_driver_enabled and nthreads
                     and clocks.mean() >= next_epoch_at):
                 last_epoch_len, since_epoch = since_epoch, 0
-                ts = time.perf_counter()
-                if self._tel is not None:
-                    # Epoch control runs through the shared scalar code
-                    # (split/merge/install events come from there); pin
-                    # the stream index to the crossing access, exactly
-                    # where the scalar per-access check fires.
-                    self._tel.cur_index = hi - 1
-                if rb_on:
-                    # Catch the per-block access counters up to the
-                    # boundary (scalar increments per routed access,
-                    # faults included).
-                    b, c = np.unique(vaddrs[rb_counted:hi]
-                                     >> self._smap.home_log2,
-                                     return_counts=True)
-                    acc = rack.cp.block_accesses
-                    for blk, cnt in zip(b.tolist(), c.tolist()):
-                        acc[blk] = acc.get(blk, 0) + cnt
-                    rb_counted = hi
-                rack.cp.maybe_run_epoch(now_us=next_epoch_at,
-                                        split=rack.splitting_enabled)
-                dir_timeline.append(mmu.engine.directory.num_entries())
-                mmu.network.begin_window()
-                inflight[:] = 0
-                mig = rack.cp.take_migration_charge()
-                if mig:
-                    # Stop-the-world migration charge, as in the scalar
-                    # loop: every thread stalls for the s2s transfer.
-                    clocks += mig
-                    breakdown["switch"] += mig * nthreads
-                if self._sharded and self._smap.version != smap_ver:
-                    # The rebalancer re-homed blocks: recompute the
-                    # routing suffix so accesses from here on use the
-                    # new homes (committed chunks keep at-access homes).
-                    smap_ver = self._smap.version
-                    home_acc[hi:] = self._smap.home_of_batch(vaddrs[hi:])
-                    cross_acc[hi:] = home_acc[hi:] != ingress_acc[hi:]
-                next_epoch_at += rack.epoch_us
-                self._rt = None  # splits/merges re-shape the table
-                self._dtab = None
-                if mmu.engine.directory.pending_evictions:
-                    # Epoch-time installs at capacity queued invalidations
-                    # the scalar engine drains at its next access.
-                    nk = np.flatnonzero(keep[hi:])
-                    if len(nk):
-                        self._drain_pending_host(state, hi + int(nk[0]))
-                pt["epoch_control"] += time.perf_counter() - ts
+                with spans.span("epoch_control"):
+                    ts = time.perf_counter()
+                    if self._tel is not None:
+                        # Epoch control runs through the shared scalar code
+                        # (split/merge/install events come from there); pin
+                        # the stream index to the crossing access, exactly
+                        # where the scalar per-access check fires.
+                        self._tel.cur_index = hi - 1
+                    if rb_on:
+                        # Catch the per-block access counters up to the
+                        # boundary (scalar increments per routed access,
+                        # faults included).
+                        b, c = np.unique(vaddrs[rb_counted:hi]
+                                         >> self._smap.home_log2,
+                                         return_counts=True)
+                        acc = rack.cp.block_accesses
+                        for blk, cnt in zip(b.tolist(), c.tolist()):
+                            acc[blk] = acc.get(blk, 0) + cnt
+                        rb_counted = hi
+                    rack.cp.maybe_run_epoch(now_us=next_epoch_at,
+                                            split=rack.splitting_enabled)
+                    dir_timeline.append(mmu.engine.directory.num_entries())
+                    mmu.network.begin_window()
+                    inflight[:] = 0
+                    mig = rack.cp.take_migration_charge()
+                    if mig:
+                        # Stop-the-world migration charge, as in the scalar
+                        # loop: every thread stalls for the s2s transfer.
+                        clocks += mig
+                        breakdown["switch"] += mig * nthreads
+                    if self._sharded and self._smap.version != smap_ver:
+                        # The rebalancer re-homed blocks: recompute the
+                        # routing suffix so accesses from here on use the
+                        # new homes (committed chunks keep at-access homes).
+                        smap_ver = self._smap.version
+                        home_acc[hi:] = self._smap.home_of_batch(vaddrs[hi:])
+                        cross_acc[hi:] = home_acc[hi:] != ingress_acc[hi:]
+                    next_epoch_at += rack.epoch_us
+                    self._rt = None  # splits/merges re-shape the table
+                    self._dtab = None
+                    if mmu.engine.directory.pending_evictions:
+                        # Epoch-time installs at capacity queued invalidations
+                        # the scalar engine drains at its next access.
+                        nk = np.flatnonzero(keep[hi:])
+                        if len(nk):
+                            self._drain_pending_host(state, hi + int(nk[0]))
+                    pt["epoch_control"] += time.perf_counter() - ts
             lo = hi
 
-        mmu.network._inflight = {
-            b: int(v) for b, v in enumerate(inflight) if v
-        }
-        runtime = float(clocks.max()) if n else 0.0
-        trans_lat = {
-            k: np.concatenate(v).tolist() for k, v in trans_lat.items()
-        }
-        return EmulationResult(
-            system=rack.system,
-            workload=trace.name,
-            num_blades=rack.nb,
-            threads_per_blade=rack.tpb,
-            runtime_us=runtime,
-            performance=(n / runtime) if runtime > 0 else 0.0,
-            stats=stats,
-            directory_timeline=dir_timeline,
-            epoch_reports=list(rack.cp.epoch_reports),
-            latency_breakdown_us=breakdown,
-            transition_latencies=trans_lat,
-            total_thread_us=float(clocks.sum()),
-            engine="batched",
-            phase_times=dict(self.phase_times),
-            num_shards=self._nshards,
-            shard_accesses=(np.bincount(
-                home_acc, minlength=self._nshards).tolist()
-                if self._smap is not None else []),
-            cross_shard_accesses=int(self._cross_acc),
-            rebalance_reports=list(rack.cp.rebalance_reports),
-            telemetry=self._tel,
-            fault_reports=list(rack.fault_reports),
-        )
+        with spans.span("result"):
+            mmu.network._inflight = {
+                b: int(v) for b, v in enumerate(inflight) if v
+            }
+            runtime = float(clocks.max()) if n else 0.0
+            trans_lat = {
+                k: np.concatenate(v).tolist() for k, v in trans_lat.items()
+            }
+            return EmulationResult(
+                system=rack.system,
+                workload=trace.name,
+                num_blades=rack.nb,
+                threads_per_blade=rack.tpb,
+                runtime_us=runtime,
+                performance=(n / runtime) if runtime > 0 else 0.0,
+                stats=stats,
+                directory_timeline=dir_timeline,
+                epoch_reports=list(rack.cp.epoch_reports),
+                latency_breakdown_us=breakdown,
+                transition_latencies=trans_lat,
+                total_thread_us=float(clocks.sum()),
+                engine="batched",
+                phase_times=dict(self.phase_times),
+                counters=dict(self.counters),
+                num_shards=self._nshards,
+                shard_accesses=(np.bincount(
+                    home_acc, minlength=self._nshards).tolist()
+                    if self._smap is not None else []),
+                cross_shard_accesses=int(self._cross_acc),
+                rebalance_reports=list(rack.cp.rebalance_reports),
+                telemetry=self._tel,
+                fault_reports=list(rack.fault_reports),
+            )
 
     # ------------------------------------------------------------------ #
     def _tick(self, key: str, t0: float) -> float:
@@ -764,9 +803,27 @@ class BatchedDataPlane:
         self.phase_times[key] = self.phase_times.get(key, 0.0) + (t1 - t0)
         return t1
 
+    @contextlib.contextmanager
+    def _phase(self, key: str):
+        """Phase ``key`` of ``PHASES``: the span ``mind.<key>``, and its
+        time into ``phase_times`` through ``_tick``, unless the body
+        calls the function it is given (its work was no such phase)."""
+        kept = [True]
+        with spans.span(key):
+            t0 = time.perf_counter()
+            yield kept.clear
+            if kept:
+                self._tick(key, t0)
+
+    def _count_transfer(self, operands, results) -> None:
+        """Bytes of one device call's operands and of its results."""
+        self.counters["h2d_bytes"] += sum(a.nbytes for a in operands)
+        self.counters["d2h_bytes"] += sum(r.nbytes for r in results)
+
     # ------------------------------------------------------------------ #
     # Speculative epoch chunking: snapshot / exact-crossing / rollback.
     # ------------------------------------------------------------------ #
+    @spans.spanned("snapshot")
     def _snapshot(self, clocks, inflight, breakdown, trans_lat) -> dict:
         """Capture every piece of state a chunk replay mutates, so a
         speculative chunk that overshoots the epoch boundary can be
@@ -844,6 +901,7 @@ class BatchedDataPlane:
         self._dtab = None
         self._row_of = {}
 
+    @spans.spanned("exact_crossing")
     def _exact_crossing(self, clocks0, threads_chunk, kept, charged,
                         next_epoch_at):
         """Position (unfiltered, within the chunk) of the access whose
@@ -876,6 +934,7 @@ class BatchedDataPlane:
         return None
 
     # ------------------------------------------------------------------ #
+    @spans.spanned("chunk_size")
     def _next_chunk_size(self, clocks, next_epoch_at, inflight) -> int:
         """Largest batch guaranteed not to cross the next epoch boundary
         before its final access — the worst-case *floor* under which no
@@ -944,6 +1003,7 @@ class BatchedDataPlane:
             ]
 
     # ------------------------------------------------------------------ #
+    @spans.spanned("drain")
     def _drain_pending_host(self, state, index: int) -> None:
         """Mirror ``CoherenceEngine._drain_capacity_evictions`` for
         evictions queued before replay began (prepopulation overflowed
@@ -1540,526 +1600,536 @@ class BatchedDataPlane:
         maxe = d.resources.max_directory_entries
 
         # ---- residency: installs and capacity evictions ----------------
-        t0 = time.perf_counter()
-        lg0 = d.initial_region_log2
-        evict_events: list = []
-        # Upper bound: even if every window the chunk touches were a
-        # miss, would the directory still fit?  If so the chunk cannot
-        # evict and the vectorized (conflict-free) path applies.  The
-        # bound is refined with an actual lookup when it trips: only
-        # *missing* windows consume SRAM slots, so a chunk whose misses
-        # still fit takes the vectorized path even at high occupancy.
-        rows0 = None
-        if d.shard_budgets is not None:
-            # Per-ASIC budgets: pressure is any *shard* overflowing its
-            # own slot budget, refined the same way per shard.
-            bud = np.asarray(d.shard_budgets, np.int64)
-            occ = np.array([len(l) for l in d._shard_lru], np.int64)
+        with self._phase("residency_prepass") as drop:
+            lg0 = d.initial_region_log2
+            evict_events: list = []
+            # Upper bound: even if every window the chunk touches were a
+            # miss, would the directory still fit?  If so the chunk cannot
+            # evict and the vectorized (conflict-free) path applies.  The
+            # bound is refined with an actual lookup when it trips: only
+            # *missing* windows consume SRAM slots, so a chunk whose misses
+            # still fit takes the vectorized path even at high occupancy.
+            rows0 = None
+            if d.shard_budgets is not None:
+                # Per-ASIC budgets: pressure is any *shard* overflowing its
+                # own slot budget, refined the same way per shard.
+                bud = np.asarray(d.shard_budgets, np.int64)
+                occ = np.array([len(l) for l in d._shard_lru], np.int64)
 
-            def _shard_load(wins):
-                return np.bincount(self._smap.home_of_batch(wins << lg0),
-                                   minlength=len(bud))
+                def _shard_load(wins):
+                    return np.bincount(self._smap.home_of_batch(wins << lg0),
+                                       minlength=len(bud))
 
-            pressure = bool(
-                (occ + _shard_load(np.unique(vaddr >> lg0)) > bud).any())
-            if pressure:
+                pressure = bool(
+                    (occ + _shard_load(np.unique(vaddr >> lg0)) > bud).any())
+                if pressure:
+                    rt = self._region_table()
+                    rows0 = rt.lookup(vaddr)
+                    miss = rows0 < 0
+                    load = (_shard_load(np.unique(vaddr[miss] >> lg0))
+                            if miss.any() else 0)
+                    pressure = bool((occ + load > bud).any())
+            else:
+                pressure = (len(d.entries) + len(np.unique(vaddr >> lg0)) > maxe)
+                if pressure:
+                    rt = self._region_table()
+                    rows0 = rt.lookup(vaddr)
+                    miss = rows0 < 0
+                    nmiss = (len(np.unique(vaddr[miss] >> lg0))
+                             if miss.any() else 0)
+                    pressure = len(d.entries) + nmiss > maxe
+            # A chunk that cannot defer leaves before the pre-pass: its
+            # check is counted in no phase (the snapshot path that
+            # follows replays it).
+            if pressure and defer:
+                drop()
+                return None  # mutates mid-walk; nothing touched yet
+            if not pressure:
                 rt = self._region_table()
-                rows0 = rt.lookup(vaddr)
-                miss = rows0 < 0
-                load = (_shard_load(np.unique(vaddr[miss] >> lg0))
-                        if miss.any() else 0)
-                pressure = bool((occ + load > bud).any())
-        else:
-            pressure = (len(d.entries) + len(np.unique(vaddr >> lg0)) > maxe)
-            if pressure:
-                rt = self._region_table()
-                rows0 = rt.lookup(vaddr)
-                miss = rows0 < 0
-                nmiss = (len(np.unique(vaddr[miss] >> lg0))
-                         if miss.any() else 0)
-                pressure = len(d.entries) + nmiss > maxe
-        if pressure and defer:
-            return None  # mutates mid-walk; nothing touched yet
-        if not pressure:
-            rt = self._region_table()
-            rows = rows0 if rows0 is not None else rt.lookup(vaddr)
-            if (rows < 0).any():
-                if defer:
-                    return None  # installs mutate the directory up front
+                rows = rows0 if rows0 is not None else rt.lookup(vaddr)
+                if (rows < 0).any():
+                    if defer:
+                        drop()
+                        return None  # installs mutate the directory up front
+                    if self._tel is not None:
+                        # Scalar installs each missing window at its first
+                        # missing access; stamp the events accordingly.
+                        mpos = np.flatnonzero(rows < 0)
+                        wins, first = np.unique(vaddr[mpos] >> lg0,
+                                                return_index=True)
+                        for wb, fi in zip((wins << lg0).tolist(),
+                                          gidx[mpos[first]].tolist()):
+                            self._tel.event(tev.DIR_INSTALL, index=fi,
+                                            base=wb, log2=lg0)
+                    self._install_missing_regions(
+                        np.unique(vaddr[rows < 0] >> lg0) << lg0)
+                    rt = self._region_table()
+                    rows = rt.lookup(vaddr)
+                self._dtab = None  # fast-path write-back bypasses it
+                # End-of-chunk recency: touched regions ordered by their
+                # last access (conflict-free, so vectorized instead of the
+                # sequential walk the pressure path needs).
+                rev = rows[::-1]
+                uniq, idx = np.unique(rev, return_index=True)
+                last_pos = len(rows) - 1 - idx
+                touch_rows = uniq[np.argsort(last_pos)].tolist()
+                if not defer:
+                    for j in touch_rows:
+                        d.touch_key(rt.keys[j])
+            else:
+                rt = self._device_table()  # before the walk mutates entries
+                keys_acc, installed, evict_events = (
+                    self._residency_prepass(vaddr, blade, write))
                 if self._tel is not None:
-                    # Scalar installs each missing window at its first
-                    # missing access; stamp the events accordingly.
-                    mpos = np.flatnonzero(rows < 0)
-                    wins, first = np.unique(vaddr[mpos] >> lg0,
-                                            return_index=True)
-                    for wb, fi in zip((wins << lg0).tolist(),
-                                      gidx[mpos[first]].tolist()):
-                        self._tel.event(tev.DIR_INSTALL, index=fi,
-                                        base=wb, log2=lg0)
-                self._install_missing_regions(
-                    np.unique(vaddr[rows < 0] >> lg0) << lg0)
-                rt = self._region_table()
-                rows = rt.lookup(vaddr)
-            self._dtab = None  # fast-path write-back bypasses it
-            # End-of-chunk recency: touched regions ordered by their
-            # last access (conflict-free, so vectorized instead of the
-            # sequential walk the pressure path needs).
-            rev = rows[::-1]
-            uniq, idx = np.unique(rev, return_index=True)
-            last_pos = len(rows) - 1 - idx
-            touch_rows = uniq[np.argsort(last_pos)].tolist()
-            if not defer:
-                for j in touch_rows:
-                    d.touch_key(rt.keys[j])
-        else:
-            rt = self._device_table()  # before the walk mutates entries
-            keys_acc, installed, evict_events = (
-                self._residency_prepass(vaddr, blade, write))
-            if self._tel is not None:
-                # The pre-pass walk is the scalar install/evict order;
-                # the eviction's invalidation itself is reconstructed
-                # from the kernel outputs further down.
-                for p, k in installed:
-                    self._tel.event(tev.DIR_INSTALL, index=int(gidx[p]),
-                                    base=k[0], log2=k[1])
-                for p, vk in evict_events:
-                    self._tel.event(tev.DIR_EVICT, index=int(gidx[p]),
-                                    base=vk[0], log2=vk[1])
-            self._extend_device_table([k for _, k in installed])
-            row_of = self._row_of
-            rows = np.fromiter((row_of[k] for k in keys_acc), np.int64, bk)
-            self._rt = None
-        t0 = self._tick("residency_prepass", t0)
+                    # The pre-pass walk is the scalar install/evict order;
+                    # the eviction's invalidation itself is reconstructed
+                    # from the kernel outputs further down.
+                    for p, k in installed:
+                        self._tel.event(tev.DIR_INSTALL, index=int(gidx[p]),
+                                        base=k[0], log2=k[1])
+                    for p, vk in evict_events:
+                        self._tel.event(tev.DIR_EVICT, index=int(gidx[p]),
+                                        base=vk[0], log2=vk[1])
+                self._extend_device_table([k for _, k in installed])
+                row_of = self._row_of
+                rows = np.fromiter((row_of[k] for k in keys_acc), np.int64, bk)
+                self._rt = None
 
         # ---- packet stream: accesses + injected eviction packets -------
-        if evict_events:
-            pos = np.array([p for p, _ in evict_events], np.int64)
-            vrow = np.array([row_of[k] for _, k in evict_events], np.int64)
-            pkt_rows = np.insert(rows, pos, vrow)
-            pkt_blade = np.insert(blade, pos, 0).astype(np.int32)
-            pkt_write = np.insert(write, pos, 0).astype(np.int32)
-            pkt_dense = np.insert(dense, pos, 0)
-            pkt_type = np.insert(np.zeros(bk, np.int32), pos, 1)
-            pkt_orig = np.insert(np.arange(bk, dtype=np.int64), pos, -1)
-        else:
-            pkt_rows = rows
-            pkt_blade = blade
-            pkt_write = write
-            pkt_dense = dense
-            pkt_type = np.zeros(bk, np.int32)
-            pkt_orig = np.arange(bk, dtype=np.int64)
+        with spans.span("packet_stream"):
+            if evict_events:
+                pos = np.array([p for p, _ in evict_events], np.int64)
+                vrow = np.array([row_of[k] for _, k in evict_events], np.int64)
+                pkt_rows = np.insert(rows, pos, vrow)
+                pkt_blade = np.insert(blade, pos, 0).astype(np.int32)
+                pkt_write = np.insert(write, pos, 0).astype(np.int32)
+                pkt_dense = np.insert(dense, pos, 0)
+                pkt_type = np.insert(np.zeros(bk, np.int32), pos, 1)
+                pkt_orig = np.insert(np.arange(bk, dtype=np.int64), pos, -1)
+            else:
+                pkt_rows = rows
+                pkt_blade = blade
+                pkt_write = write
+                pkt_dense = dense
+                pkt_type = np.zeros(bk, np.int32)
+                pkt_orig = np.arange(bk, dtype=np.int64)
 
-        act_rows, slot_of_pkt = np.unique(pkt_rows, return_inverse=True)
-        sa = len(act_rows)
-        slot_of_pkt = slot_of_pkt.astype(np.int32)
+            act_rows, slot_of_pkt = np.unique(pkt_rows, return_inverse=True)
+            sa = len(act_rows)
+            slot_of_pkt = slot_of_pkt.astype(np.int32)
 
-        # Dense spans + clear-masks of the active regions.
-        d0, npages = pm.region_dense_span(
-            rt.bases[act_rows], (1 << rt.log2s[act_rows].astype(np.int64)))
-        bitoff = (d0 & 31).astype(np.int64)
-        w0 = (d0 >> 5).astype(np.int32)
-        span = max(1, next_pow2(int(((bitoff + npages + 31) // 32).max())))
-        j32 = np.arange(span, dtype=np.int64)[None, :] * 32
-        sbit = np.clip(bitoff[:, None] - j32, 0, 32).astype(np.uint64)
-        ebit = np.clip((bitoff + npages)[:, None] - j32, 0, 32).astype(np.uint64)
-        below = lambda k: (np.uint64(1) << k) - np.uint64(1)  # noqa: E731
-        cmask = ((below(ebit) ^ below(sbit)) & np.uint64(0xFFFFFFFF)).astype(
-            np.uint32).view(np.int32)
+            # Dense spans + clear-masks of the active regions.
+            d0, npages = pm.region_dense_span(
+                rt.bases[act_rows], (1 << rt.log2s[act_rows].astype(np.int64)))
+            bitoff = (d0 & 31).astype(np.int64)
+            w0 = (d0 >> 5).astype(np.int32)
+            span = max(1, next_pow2(int(((bitoff + npages + 31) // 32).max())))
+            j32 = np.arange(span, dtype=np.int64)[None, :] * 32
+            sbit = np.clip(bitoff[:, None] - j32, 0, 32).astype(np.uint64)
+            ebit = np.clip((bitoff + npages)[:, None] - j32, 0, 32).astype(np.uint64)
+            below = lambda k: (np.uint64(1) << k) - np.uint64(1)  # noqa: E731
+            cmask = ((below(ebit) ^ below(sbit)) & np.uint64(0xFFFFFFFF)).astype(
+                np.uint32).view(np.int32)
 
         # ---- cache-occupancy pre-pass: blade-cache eviction packets ----
-        t0 = time.perf_counter()
-        host_clears: list = []
-        if self._cache_shadows is not None:
-            assert not defer  # run() never defers with shadows armed
-            cache_events = self._cache_events(
-                slot_of_pkt, pkt_type, pkt_blade, pkt_write, pkt_dense,
-                rt.state[act_rows], rt.sharers[act_rows], rt.owner[act_rows],
-                d0, npages)
-            if cache_events:
-                cpos = np.array([e[0] for e in cache_events], np.int64)
-                cbl = np.array([e[1] for e in cache_events], np.int32)
-                cpg = np.array([e[2] for e in cache_events], np.int64)
-                cdirty = np.array([e[3] for e in cache_events], bool)
-                ndirty = int(cdirty.sum())
-                if self._tel is not None:
-                    # Each eviction fires inside the triggering access's
-                    # ``BladePageCache.insert`` in the scalar engine;
-                    # ``pkt_orig`` (pre-insertion) maps the packet
-                    # position back to that access.
-                    co = pkt_orig[cpos]
-                    cva = pm.vaddr_of(cpg)
-                    for gi, b, va, dy in zip(gidx[co].tolist(),
-                                             cbl.tolist(), cva.tolist(),
-                                             cdirty.tolist()):
-                        self._tel.event(
-                            tev.CACHE_EVICT_DIRTY if dy
-                            else tev.CACHE_EVICT_CLEAN,
-                            index=gi, blade=b, base=va, pages=1)
-                # Scalar parity: evictions inside BladePageCache.insert
-                # count dirty write-backs into flushed_pages, charge no
-                # latency, and never count as invalidations.
-                engine.stats.evicted_dirty += ndirty
-                engine.stats.evicted_clean += len(cache_events) - ndirty
-                engine.stats.flushed_pages += ndirty
-                # The lane that must execute each eviction is the one
-                # owning the victim's plane bit: the active region
-                # covering the victim page.  Active spans are nested or
-                # disjoint (pow2 buddy regions), so a prefix-max over
-                # the spans sorted by start finds the covering one.
-                starts = np.where(npages > 0, d0, np.iinfo(np.int64).max)
-                order = np.argsort(starts, kind="stable")
-                reach = np.maximum.accumulate((d0 + npages)[order])
-                idx = np.searchsorted(starts[order], cpg, side="right") - 1
-                j = np.searchsorted(reach, cpg, side="right")
-                cov = (idx >= 0) & (j <= idx)
-                if cov.any():
-                    ip = cpos[cov]
-                    cslot = order[j[cov]].astype(np.int32)
-                    slot_of_pkt = np.insert(slot_of_pkt, ip, cslot)
-                    pkt_blade = np.insert(pkt_blade, ip, cbl[cov])
-                    pkt_write = np.insert(pkt_write, ip, 0).astype(np.int32)
-                    pkt_dense = np.insert(pkt_dense, ip, cpg[cov])
-                    pkt_type = np.insert(pkt_type, ip, 2)
-                    pkt_orig = np.insert(pkt_orig, ip, -1)
-                # Victims outside every active region: no device packet
-                # can read their bits this chunk, so clear them on the
-                # host after the lane merge (their words are unowned and
-                # survive the merge unchanged).
-                host_clears = list(zip(cbl[~cov].tolist(), cpg[~cov].tolist()))
-        t0 = self._tick("cache_prepass", t0)
+        with self._phase("cache_prepass"):
+            host_clears: list = []
+            if self._cache_shadows is not None:
+                assert not defer  # run() never defers with shadows armed
+                cache_events = self._cache_events(
+                    slot_of_pkt, pkt_type, pkt_blade, pkt_write, pkt_dense,
+                    rt.state[act_rows], rt.sharers[act_rows], rt.owner[act_rows],
+                    d0, npages)
+                if cache_events:
+                    cpos = np.array([e[0] for e in cache_events], np.int64)
+                    cbl = np.array([e[1] for e in cache_events], np.int32)
+                    cpg = np.array([e[2] for e in cache_events], np.int64)
+                    cdirty = np.array([e[3] for e in cache_events], bool)
+                    ndirty = int(cdirty.sum())
+                    if self._tel is not None:
+                        # Each eviction fires inside the triggering access's
+                        # ``BladePageCache.insert`` in the scalar engine;
+                        # ``pkt_orig`` (pre-insertion) maps the packet
+                        # position back to that access.
+                        co = pkt_orig[cpos]
+                        cva = pm.vaddr_of(cpg)
+                        for gi, b, va, dy in zip(gidx[co].tolist(),
+                                                 cbl.tolist(), cva.tolist(),
+                                                 cdirty.tolist()):
+                            self._tel.event(
+                                tev.CACHE_EVICT_DIRTY if dy
+                                else tev.CACHE_EVICT_CLEAN,
+                                index=gi, blade=b, base=va, pages=1)
+                    # Scalar parity: evictions inside BladePageCache.insert
+                    # count dirty write-backs into flushed_pages, charge no
+                    # latency, and never count as invalidations.
+                    engine.stats.evicted_dirty += ndirty
+                    engine.stats.evicted_clean += len(cache_events) - ndirty
+                    engine.stats.flushed_pages += ndirty
+                    # The lane that must execute each eviction is the one
+                    # owning the victim's plane bit: the active region
+                    # covering the victim page.  Active spans are nested or
+                    # disjoint (pow2 buddy regions), so a prefix-max over
+                    # the spans sorted by start finds the covering one.
+                    starts = np.where(npages > 0, d0, np.iinfo(np.int64).max)
+                    order = np.argsort(starts, kind="stable")
+                    reach = np.maximum.accumulate((d0 + npages)[order])
+                    idx = np.searchsorted(starts[order], cpg, side="right") - 1
+                    j = np.searchsorted(reach, cpg, side="right")
+                    cov = (idx >= 0) & (j <= idx)
+                    if cov.any():
+                        ip = cpos[cov]
+                        cslot = order[j[cov]].astype(np.int32)
+                        slot_of_pkt = np.insert(slot_of_pkt, ip, cslot)
+                        pkt_blade = np.insert(pkt_blade, ip, cbl[cov])
+                        pkt_write = np.insert(pkt_write, ip, 0).astype(np.int32)
+                        pkt_dense = np.insert(pkt_dense, ip, cpg[cov])
+                        pkt_type = np.insert(pkt_type, ip, 2)
+                        pkt_orig = np.insert(pkt_orig, ip, -1)
+                    # Victims outside every active region: no device packet
+                    # can read their bits this chunk, so clear them on the
+                    # host after the lane merge (their words are unowned and
+                    # survive the merge unchanged).
+                    host_clears = list(zip(cbl[~cov].tolist(), cpg[~cov].tolist()))
 
-        # Overlapping active regions (coarse re-installs over surviving
-        # split children) share cache-plane bits: pin each overlap
-        # component to one lane so their packets serialize.  Components
-        # never span shards — overlap needs overlapping VA, and shards
-        # partition the VA space at max-region blocks.
-        group_of_slot = None
-        if sa > 1:
-            ab = rt.bases[act_rows]
-            ae = ab + (np.int64(1) << rt.log2s[act_rows].astype(np.int64))
-            order = np.argsort(ab, kind="stable")
-            run_end = np.maximum.accumulate(ae[order])
-            new_comp = np.ones(sa, bool)
-            new_comp[1:] = ab[order][1:] >= run_end[:-1]
-            comp = np.cumsum(new_comp) - 1
-            if comp[-1] + 1 < sa:
-                group_of_slot = np.empty(sa, np.int64)
-                group_of_slot[order] = comp
+        with self._phase("schedule"):
+            # Overlapping active regions (coarse re-installs over surviving
+            # split children) share cache-plane bits: pin each overlap
+            # component to one lane so their packets serialize.  Components
+            # never span shards — overlap needs overlapping VA, and shards
+            # partition the VA space at max-region blocks.
+            group_of_slot = None
+            if sa > 1:
+                ab = rt.bases[act_rows]
+                ae = ab + (np.int64(1) << rt.log2s[act_rows].astype(np.int64))
+                order = np.argsort(ab, kind="stable")
+                run_end = np.maximum.accumulate(ae[order])
+                new_comp = np.ones(sa, bool)
+                new_comp[1:] = ab[order][1:] >= run_end[:-1]
+                comp = np.cumsum(new_comp) - 1
+                if comp[-1] + 1 < sa:
+                    group_of_slot = np.empty(sa, np.int64)
+                    group_of_slot[order] = comp
 
-        words = state.planes.shape[1]
-        npkt = len(slot_of_pkt)
-        # Directory-eviction packets carry no page; accesses and
-        # blade-cache eviction packets address (dense page) - (slot w0).
-        rw_val = np.where(
-            pkt_type == 1, 0,
-            (pkt_dense >> 5) - w0[slot_of_pkt].astype(np.int64)).astype(np.int32)
-        bit_val = np.where(pkt_type == 1, 0, pkt_dense & 31).astype(np.int32)
-        dir_pre = np.stack(
-            [rt.state[act_rows], rt.sharers[act_rows], rt.owner[act_rows],
-             rt.prepop[act_rows].astype(np.int32)], axis=1).astype(np.int32)
-        nword = ((bitoff + npages + 31) >> 5).astype(np.int64)
+            words = state.planes.shape[1]
+            npkt = len(slot_of_pkt)
+            # Directory-eviction packets carry no page; accesses and
+            # blade-cache eviction packets address (dense page) - (slot w0).
+            rw_val = np.where(
+                pkt_type == 1, 0,
+                (pkt_dense >> 5) - w0[slot_of_pkt].astype(np.int64)).astype(np.int32)
+            bit_val = np.where(pkt_type == 1, 0, pkt_dense & 31).astype(np.int32)
+            dir_pre = np.stack(
+                [rt.state[act_rows], rt.sharers[act_rows], rt.owner[act_rows],
+                 rt.prepop[act_rows].astype(np.int32)], axis=1).astype(np.int32)
+            nword = ((bitoff + npages + 31) >> 5).astype(np.int64)
 
-        # ---- per-shard device replay -----------------------------------
-        # One wave schedule and one MSI kernel invocation per home
-        # shard: each shard's conflict lanes serialize only that shard's
-        # regions, and the subsets are exact (regions never straddle
-        # shards, so neither packets nor overlap groups do).  The
-        # single-switch rack degenerates to one invocation — the
-        # original path.
-        shard_of_slot = rt.shard[act_rows] if self._sharded else None
-        w1_all = np.zeros(npkt, np.int64)
-        w2_all = np.zeros(npkt, np.int64)
-        flushed_all = np.zeros(npkt, np.int64)
-        dir_n = dir_pre.copy()
-        merged = state.planes.copy()
+            # ---- per-shard device replay -----------------------------------
+            # One wave schedule and one MSI kernel invocation per home
+            # shard: each shard's conflict lanes serialize only that shard's
+            # regions, and the subsets are exact (regions never straddle
+            # shards, so neither packets nor overlap groups do).  The
+            # single-switch rack degenerates to one invocation — the
+            # original path.
+            shard_of_slot = rt.shard[act_rows] if self._sharded else None
+            w1_all = np.zeros(npkt, np.int64)
+            w2_all = np.zeros(npkt, np.int64)
+            flushed_all = np.zeros(npkt, np.int64)
+            dir_n = dir_pre.copy()
+            merged = state.planes.copy()
 
         for _shard, pkt_idx, slots_sel in partition_by_shard(
                 slot_of_pkt, sa, shard_of_slot):
-            sa_s = len(slots_sel)
-            local_of_global = np.full(sa, -1, np.int32)
-            local_of_global[slots_sel] = np.arange(sa_s, dtype=np.int32)
-            sub_slot = local_of_global[slot_of_pkt[pkt_idx]]
-            sub_group = None
-            if group_of_slot is not None:
-                _, sub_group = np.unique(group_of_slot[slots_sel],
-                                         return_inverse=True)
-            lanes = self.lanes
-            if lanes is None:
-                # Wave count is floored by the hottest scheduling group;
-                # lanes beyond batch/hottest add vmap width (per-wave
-                # cost) without removing waves.
-                counts = np.bincount(sub_slot, minlength=max(sa_s, 1))
-                if sub_group is not None:
-                    hot = float(np.bincount(sub_group,
-                                            weights=counts).max())
-                else:
-                    hot = float(counts.max()) if sa_s else 1.0
-                ideal = len(sub_slot) / max(1.0, hot)
-                lanes = int(min(16, max(2, next_pow2(int(ideal) + 1) // 2)))
-            sched = build_wave_schedule(sub_slot, sa_s, lanes=lanes,
-                                        group_of_slot=sub_group)
-            g = sched.lanes
-            s_dev = next_pow2(sched.slots_per_lane + 1)
-            l_dev = max(1, next_pow2(sched.num_waves))
-            dummy = s_dev - 1
+            with self._phase("schedule"):
+                sa_s = len(slots_sel)
+                local_of_global = np.full(sa, -1, np.int32)
+                local_of_global[slots_sel] = np.arange(sa_s, dtype=np.int32)
+                sub_slot = local_of_global[slot_of_pkt[pkt_idx]]
+                sub_group = None
+                if group_of_slot is not None:
+                    _, sub_group = np.unique(group_of_slot[slots_sel],
+                                             return_inverse=True)
+                lanes = self.lanes
+                if lanes is None:
+                    # Wave count is floored by the hottest scheduling group;
+                    # lanes beyond batch/hottest add vmap width (per-wave
+                    # cost) without removing waves.
+                    counts = np.bincount(sub_slot, minlength=max(sa_s, 1))
+                    if sub_group is not None:
+                        hot = float(np.bincount(sub_group,
+                                                weights=counts).max())
+                    else:
+                        hot = float(counts.max()) if sa_s else 1.0
+                    ideal = len(sub_slot) / max(1.0, hot)
+                    lanes = int(min(16, max(2, next_pow2(int(ideal) + 1) // 2)))
+                sched = build_wave_schedule(sub_slot, sa_s, lanes=lanes,
+                                            group_of_slot=sub_group)
+                g = sched.lanes
+                s_dev = next_pow2(sched.slots_per_lane + 1)
+                l_dev = max(1, next_pow2(sched.num_waves))
+                dummy = s_dev - 1
 
-            def lane_stream(per_pkt, fill, dtype=np.int32):
-                out = np.full((g, l_dev), fill, dtype)
-                out[:, : sched.num_waves][sched.acc_valid] = per_pkt[
-                    sched.acc_index[sched.acc_valid]]
-                return out
+                def lane_stream(per_pkt, fill, dtype=np.int32):
+                    out = np.full((g, l_dev), fill, dtype)
+                    out[:, : sched.num_waves][sched.acc_valid] = per_pkt[
+                        sched.acc_index[sched.acc_valid]]
+                    return out
 
-            acc_slot = lane_stream(sched.local_of_slot[sub_slot], dummy)
-            acc_blade = lane_stream(pkt_blade[pkt_idx], 0)
-            acc_write = lane_stream(pkt_write[pkt_idx], 0)
-            acc_type = lane_stream(pkt_type[pkt_idx], 0)
-            acc_w0 = lane_stream(w0[slot_of_pkt[pkt_idx]], words)  # pad
-            acc_rw = lane_stream(rw_val[pkt_idx], 0)
-            acc_bit = lane_stream(bit_val[pkt_idx], 0)
-            acc_valid = np.zeros((g, l_dev), bool)
-            acc_valid[:, : sched.num_waves] = sched.acc_valid
+                acc_slot = lane_stream(sched.local_of_slot[sub_slot], dummy)
+                acc_blade = lane_stream(pkt_blade[pkt_idx], 0)
+                acc_write = lane_stream(pkt_write[pkt_idx], 0)
+                acc_type = lane_stream(pkt_type[pkt_idx], 0)
+                acc_w0 = lane_stream(w0[slot_of_pkt[pkt_idx]], words)  # pad
+                acc_rw = lane_stream(rw_val[pkt_idx], 0)
+                acc_bit = lane_stream(bit_val[pkt_idx], 0)
+                acc_valid = np.zeros((g, l_dev), bool)
+                acc_valid[:, : sched.num_waves] = sched.acc_valid
 
-            # Per-lane directory rows + clear-masks + plane copies.
-            lane_idx = sched.lane_of_slot
-            local_idx = sched.local_of_slot
-            dirrows = np.zeros((g, s_dev, 4), np.int32)
-            dirrows[lane_idx, local_idx] = dir_pre[slots_sel]
-            cm_dev = np.zeros((g, s_dev, span), np.int32)
-            cm_dev[lane_idx, local_idx] = cmask[slots_sel]
-            planes = np.zeros((g, 2 * nb, words + span), np.int32)
-            planes[:, :, :words] = state.planes[None]
-            t0 = self._tick("schedule", t0)
+                # Per-lane directory rows + clear-masks + plane copies.
+                lane_idx = sched.lane_of_slot
+                local_idx = sched.local_of_slot
+                dirrows = np.zeros((g, s_dev, 4), np.int32)
+                dirrows[lane_idx, local_idx] = dir_pre[slots_sel]
+                cm_dev = np.zeros((g, s_dev, span), np.int32)
+                cm_dev[lane_idx, local_idx] = cmask[slots_sel]
+                planes = np.zeros((g, 2 * nb, words + span), np.int32)
+                planes[:, :, :words] = state.planes[None]
 
-            out = _replay(
-                jnp.asarray(np.int32(sched.num_waves)),
-                jnp.asarray(self._dkc),
-                jnp.asarray(acc_slot), jnp.asarray(acc_blade),
-                jnp.asarray(acc_write), jnp.asarray(acc_valid),
-                jnp.asarray(acc_type),
-                jnp.asarray(acc_w0), jnp.asarray(acc_rw),
-                jnp.asarray(acc_bit),
-                jnp.asarray(dirrows), jnp.asarray(cm_dev),
-                jnp.asarray(planes))
-            (dir_o, planes_o, w1_o, w2_o, w3_o) = map(np.asarray, out)
-            t0 = self._tick("device", t0)
+            with self._phase("device"):
+                args = (jnp.asarray(np.int32(sched.num_waves)),
+                        jnp.asarray(self._dkc),
+                        jnp.asarray(acc_slot), jnp.asarray(acc_blade),
+                        jnp.asarray(acc_write), jnp.asarray(acc_valid),
+                        jnp.asarray(acc_type),
+                        jnp.asarray(acc_w0), jnp.asarray(acc_rw),
+                        jnp.asarray(acc_bit),
+                        jnp.asarray(dirrows), jnp.asarray(cm_dev),
+                        jnp.asarray(planes))
+                out = tuple(map(np.asarray, _replay(*args)))
+                (dir_o, planes_o, w1_o, w2_o, w3_o) = out
+                self._count_transfer(args, out)
+                self.counters["waves"] += sched.num_waves
+                self.counters["wave_slots"] += g * sched.num_waves
+                self.counters["packets"] += len(sub_slot)
 
-            # ---- unpack this shard's per-packet output words ----------
-            vmask = sched.acc_valid
-            posm = pkt_idx[sched.acc_index[vmask]]
-            w1_all[posm] = w1_o[:, : sched.num_waves][vmask]
-            w2_all[posm] = w2_o[:, : sched.num_waves][vmask]
-            flushed_all[posm] = w3_o[:, : sched.num_waves][vmask]
-            dir_n[slots_sel] = dir_o[lane_idx, local_idx]
+            with self._phase("merge_writeback"):
+                # ---- unpack this shard's per-packet output words ----------
+                vmask = sched.acc_valid
+                posm = pkt_idx[sched.acc_index[vmask]]
+                w1_all[posm] = w1_o[:, : sched.num_waves][vmask]
+                w2_all[posm] = w2_o[:, : sched.num_waves][vmask]
+                flushed_all[posm] = w3_o[:, : sched.num_waves][vmask]
+                dir_n[slots_sel] = dir_o[lane_idx, local_idx]
 
-            # ---- merge lane planes by bit ownership -------------------
-            # Ownership scatter over (lane, word) pairs: expand each
-            # active row to exactly its occupied words (most regions
-            # span one) — O(sum of spans), not O(sa * max_span).
-            # Shards own disjoint bit sets, so the per-shard merges
-            # compose in any order.
-            own = np.zeros((g, words + span), np.int32)
-            nword_s = nword[slots_sel]
-            totw = int(nword_s.sum())
-            if totw:
-                repr_ = np.repeat(np.arange(sa_s), nword_s)
-                offs = np.arange(totw) - np.repeat(
-                    nword_s.cumsum() - nword_s, nword_s)
-                grow = slots_sel[repr_]
-                np.bitwise_or.at(
-                    own, (lane_idx[repr_], w0[grow] + offs),
-                    cmask[grow, offs])
-            all_owned = np.bitwise_or.reduce(own, axis=0)
-            merged &= ~all_owned[:words]
-            for gg in range(g):
-                merged |= planes_o[gg, :, :words] & own[gg, :words]
-            t0 = self._tick("merge_writeback", t0)
+                # ---- merge lane planes by bit ownership -------------------
+                # Ownership scatter over (lane, word) pairs: expand each
+                # active row to exactly its occupied words (most regions
+                # span one) — O(sum of spans), not O(sa * max_span).
+                # Shards own disjoint bit sets, so the per-shard merges
+                # compose in any order.
+                own = np.zeros((g, words + span), np.int32)
+                nword_s = nword[slots_sel]
+                totw = int(nword_s.sum())
+                if totw:
+                    repr_ = np.repeat(np.arange(sa_s), nword_s)
+                    offs = np.arange(totw) - np.repeat(
+                        nword_s.cumsum() - nword_s, nword_s)
+                    grow = slots_sel[repr_]
+                    np.bitwise_or.at(
+                        own, (lane_idx[repr_], w0[grow] + offs),
+                        cmask[grow, offs])
+                all_owned = np.bitwise_or.reduce(own, axis=0)
+                merged &= ~all_owned[:words]
+                for gg in range(g):
+                    merged |= planes_o[gg, :, :words] & own[gg, :words]
 
-        inval_all = w1_all >> 7
-        ninv_all = np.zeros(npkt, np.int64)
-        for c in range(nb):
-            ninv_all += (inval_all >> c) & 1
-        nfalse_all = w2_all & 0x7FFF
-        dropped_all = w2_all >> 15
-        is_acc = pkt_orig >= 0
-        nhits = int((w1_all[is_acc] & 1).sum())
+        with self._phase("merge_writeback"):
+            inval_all = w1_all >> 7
+            ninv_all = np.zeros(npkt, np.int64)
+            for c in range(nb):
+                ninv_all += (inval_all >> c) & 1
+            nfalse_all = w2_all & 0x7FFF
+            dropped_all = w2_all >> 15
+            is_acc = pkt_orig >= 0
+            nhits = int((w1_all[is_acc] & 1).sum())
 
-        if self._tel is not None and evict_events:
-            # Directory-eviction packets: the multicast the kernel
-            # executed for each victim, stamped at the evicting access
-            # (scalar queues then drains within the same ``access()``).
-            evp = np.flatnonzero(pkt_type == 1)
-            for k, (p, vk) in enumerate(evict_events):
-                tgt = int(inval_all[evp[k]])
-                if not tgt:
-                    continue
-                gi = int(gidx[p])
-                fl = int(flushed_all[evp[k]])
-                self._tel.event(tev.INVALIDATE, index=gi, base=vk[0],
-                                log2=vk[1], targets=tgt,
-                                pages=int(dropped_all[evp[k]]),
-                                false_pages=int(nfalse_all[evp[k]]),
-                                flushed=fl)
-                if fl:
-                    self._tel.event(tev.WRITEBACK, index=gi, base=vk[0],
-                                    log2=vk[1], pages=fl)
-
-        # ---- write-back: directory entries + per-region epoch stats ---
-        # Per-region Bounded-Splitting counters, reduced host-side from
-        # the packed words: accesses and false invalidations per slot,
-        # counting only packets after the slot's last eviction packet (a
-        # re-install starts with fresh epoch counters, exactly the
-        # kernel's old in-loop reset).
-        fac_n = acnt_n = None
-        if rack.splitting_enabled:
-            acc_pkt = pkt_type == 0
-            if evict_events:
-                lastev = np.full(sa, -1, np.int64)
+            if self._tel is not None and evict_events:
+                # Directory-eviction packets: the multicast the kernel
+                # executed for each victim, stamped at the evicting access
+                # (scalar queues then drains within the same ``access()``).
                 evp = np.flatnonzero(pkt_type == 1)
-                np.maximum.at(lastev, slot_of_pkt[evp], evp)
-                acc_pkt = acc_pkt & (np.arange(npkt) > lastev[slot_of_pkt])
-            fac_n = np.zeros(sa, np.int64)
-            np.add.at(fac_n, slot_of_pkt[acc_pkt], nfalse_all[acc_pkt])
-            acnt_n = np.bincount(slot_of_pkt[acc_pkt], minlength=sa)
-        # Under capacity pressure an entry can be evicted and re-installed
-        # within the chunk: its host object is then a *fresh* Invalid
-        # entry even when the device row ends where it started, so every
-        # active row must be written back, not just value-changed ones.
-        if pressure:
-            touched = range(sa)
-        else:
-            touched = np.flatnonzero((dir_n != dir_pre).any(axis=1)).tolist()
+                for k, (p, vk) in enumerate(evict_events):
+                    tgt = int(inval_all[evp[k]])
+                    if not tgt:
+                        continue
+                    gi = int(gidx[p])
+                    fl = int(flushed_all[evp[k]])
+                    self._tel.event(tev.INVALIDATE, index=gi, base=vk[0],
+                                    log2=vk[1], targets=tgt,
+                                    pages=int(dropped_all[evp[k]]),
+                                    false_pages=int(nfalse_all[evp[k]]),
+                                    flushed=fl)
+                    if fl:
+                        self._tel.event(tev.WRITEBACK, index=gi, base=vk[0],
+                                        log2=vk[1], pages=fl)
 
-        def commit_state():
-            if defer:
-                for j in touch_rows:
-                    d.touch_key(rt.keys[j])
-            state.planes = merged
-            if host_clears:
-                hb = np.array([b for b, _ in host_clears], np.int64)
-                hp = np.array([p for _, p in host_clears], np.int64)
-                hm = ~(np.uint32(1) << (hp & 31).astype(np.uint32)).view(
-                    np.int32)
-                for rowbase in (hb, nb + hb):  # presence + dirty planes
-                    np.bitwise_and.at(state.planes, (rowbase, hp >> 5), hm)
-            for j in touched:
-                key = rt.keys[act_rows[j]]
-                e = d.entries.get(key)
-                if e is not None:
-                    e.state = MSIState(int(dir_n[j, 0]))
-                    e.sharers = int(dir_n[j, 1])
-                    e.owner = int(dir_n[j, 2])
-                if not dir_n[j, 3]:
-                    engine._prepopulated.discard(key)
-            if rack.splitting_enabled:  # RegionStats feed Bounded Splitting
-                for j in np.flatnonzero((fac_n > 0) | (acnt_n > 0)).tolist():
-                    rst = d.stats.get(rt.keys[act_rows[j]])
-                    if rst is not None:
-                        rst.false_invalidations += int(fac_n[j])
-                        rst.accesses += int(acnt_n[j])
-            rt.state[act_rows] = dir_n[:, 0]
-            rt.sharers[act_rows] = dir_n[:, 1]
-            rt.owner[act_rows] = dir_n[:, 2]
-            rt.prepop[act_rows] = dir_n[:, 3].astype(bool)
-            stats = engine.stats
-            stats.accesses += bk
-            stats.local_hits += nhits
-            stats.remote_fetches += bk - nhits
-            stats.invalidations += int(ninv_all.sum())
-            stats.invalidated_pages += int(dropped_all.sum())
-            stats.flushed_pages += int(flushed_all.sum())
-            stats.false_invalidated_pages += int(nfalse_all.sum())
+            # ---- write-back: directory entries + per-region epoch stats ---
+            # Per-region Bounded-Splitting counters, reduced host-side from
+            # the packed words: accesses and false invalidations per slot,
+            # counting only packets after the slot's last eviction packet (a
+            # re-install starts with fresh epoch counters, exactly the
+            # kernel's old in-loop reset).
+            fac_n = acnt_n = None
+            if rack.splitting_enabled:
+                acc_pkt = pkt_type == 0
+                if evict_events:
+                    lastev = np.full(sa, -1, np.int64)
+                    evp = np.flatnonzero(pkt_type == 1)
+                    np.maximum.at(lastev, slot_of_pkt[evp], evp)
+                    acc_pkt = acc_pkt & (np.arange(npkt) > lastev[slot_of_pkt])
+                fac_n = np.zeros(sa, np.int64)
+                np.add.at(fac_n, slot_of_pkt[acc_pkt], nfalse_all[acc_pkt])
+                acnt_n = np.bincount(slot_of_pkt[acc_pkt], minlength=sa)
+            # Under capacity pressure an entry can be evicted and re-installed
+            # within the chunk: its host object is then a *fresh* Invalid
+            # entry even when the device row ends where it started, so every
+            # active row must be written back, not just value-changed ones.
+            if pressure:
+                touched = range(sa)
+            else:
+                touched = np.flatnonzero((dir_n != dir_pre).any(axis=1)).tolist()
 
-        if not defer:
-            commit_state()
-        t0 = self._tick("merge_writeback", t0)
+            def commit_state():
+                if defer:
+                    for j in touch_rows:
+                        d.touch_key(rt.keys[j])
+                state.planes = merged
+                if host_clears:
+                    hb = np.array([b for b, _ in host_clears], np.int64)
+                    hp = np.array([p for _, p in host_clears], np.int64)
+                    hm = ~(np.uint32(1) << (hp & 31).astype(np.uint32)).view(
+                        np.int32)
+                    for rowbase in (hb, nb + hb):  # presence + dirty planes
+                        np.bitwise_and.at(state.planes, (rowbase, hp >> 5), hm)
+                for j in touched:
+                    key = rt.keys[act_rows[j]]
+                    e = d.entries.get(key)
+                    if e is not None:
+                        e.state = MSIState(int(dir_n[j, 0]))
+                        e.sharers = int(dir_n[j, 1])
+                        e.owner = int(dir_n[j, 2])
+                    if not dir_n[j, 3]:
+                        engine._prepopulated.discard(key)
+                if rack.splitting_enabled:  # RegionStats feed Bounded Splitting
+                    for j in np.flatnonzero((fac_n > 0) | (acnt_n > 0)).tolist():
+                        rst = d.stats.get(rt.keys[act_rows[j]])
+                        if rst is not None:
+                            rst.false_invalidations += int(fac_n[j])
+                            rst.accesses += int(acnt_n[j])
+                rt.state[act_rows] = dir_n[:, 0]
+                rt.sharers[act_rows] = dir_n[:, 1]
+                rt.owner[act_rows] = dir_n[:, 2]
+                rt.prepop[act_rows] = dir_n[:, 3].astype(bool)
+                stats = engine.stats
+                stats.accesses += bk
+                stats.local_hits += nhits
+                stats.remote_fetches += bk - nhits
+                stats.invalidations += int(ninv_all.sum())
+                stats.invalidated_pages += int(dropped_all.sum())
+                stats.flushed_pages += int(flushed_all.sum())
+                stats.false_invalidated_pages += int(nfalse_all.sum())
+
+            if not defer:
+                commit_state()
 
         # ---- exact-order latency reconstruction -----------------------
-        # The lanes emitted per-access action words; queueing delay
-        # depends on the original cross-lane interleaving, so rebuild it
-        # here (NetworkModel.latency, vectorized over the chunk).
-        # Eviction packets (directory and blade-cache alike) charge no
-        # latency — the scalar drain and BladePageCache.insert's
-        # write-back are both free in NetworkModel terms — and are
-        # filtered back out of the stream first.
-        flags = w1_all[is_acc] & 0x7F
-        invals = inval_all[is_acc]
-        hit = (flags & 1) == 1
-        fetch = ((flags >> 1) & 1) == 1
-        seq = ((flags >> 2) & 1) == 1
-        par = ((flags >> 3) & 1) == 1
-        kind = flags >> 4
-        has_inv = invals != 0
-        ind = ((invals[:, None] >> np.arange(nb)) & 1).astype(np.int64)
-        cum_excl = np.cumsum(ind, axis=0) - ind + inflight[None, :]
-        q = np.where(ind > 0, cum_excl, 0).max(axis=1).astype(np.float64)
-        k_local, k_rdma, k_inval, k_tlb, k_queue, k_switch, k_s2s = kvec
-        queue_f = np.where(has_inv, k_queue * q, 0.0)
-        tlb_f = np.where(has_inv, k_tlb, 0.0)
-        inv_f = np.where(has_inv, k_inval, 0.0)
-        fetch_f = np.where(fetch, k_rdma, 0.0)
-        pure_local = hit & ~has_inv
-        lb_fetch = np.where(
-            pure_local, k_local,
-            np.where(par, np.maximum(fetch_f, inv_f + queue_f), fetch_f))
-        lb_inv = np.where(seq, inv_f, 0.0)
-        lb_tlb = np.where(par | pure_local, 0.0, tlb_f)
-        lb_queue = np.where(par | pure_local, 0.0, queue_f)
-        # Cross-shard accesses traverse the switch-to-switch link to
-        # their home pipeline — the hop rides the switch term, exactly
-        # where ShardedRack._route puts it (pure local hits never leave
-        # the blade and pay nothing).
-        cross_hop = cross & ~pure_local
-        lb_switch = np.where(pure_local, 0.0, k_switch) + np.where(
-            cross_hop, k_s2s, 0.0)
-        # Lossy-fabric retransmission charge: pure local hits never
-        # leave the blade; faults never reach this path (filtered by
-        # `keep`).  Same trailing position in the sum as
-        # LatencyBreakdown.total_us — the order is load-bearing for
-        # float-exact parity.
-        if self._fab is not None:
-            lb_retry = np.where(pure_local, 0.0, self._fab[2][gidx])
-        else:
-            lb_retry = np.zeros(len(hit))
-        total = (lb_fetch + lb_inv + lb_tlb + lb_queue + lb_switch
-                 + lb_retry)
-        if pso:
-            charged = np.where(
-                (write == 1) & ~hit, k_switch + lb_queue, total)
-        else:
-            charged = total
+        with self._phase("latency_reconstruct"):
+            # The lanes emitted per-access action words; queueing delay
+            # depends on the original cross-lane interleaving, so rebuild it
+            # here (NetworkModel.latency, vectorized over the chunk).
+            # Eviction packets (directory and blade-cache alike) charge no
+            # latency — the scalar drain and BladePageCache.insert's
+            # write-back are both free in NetworkModel terms — and are
+            # filtered back out of the stream first.
+            flags = w1_all[is_acc] & 0x7F
+            invals = inval_all[is_acc]
+            hit = (flags & 1) == 1
+            fetch = ((flags >> 1) & 1) == 1
+            seq = ((flags >> 2) & 1) == 1
+            par = ((flags >> 3) & 1) == 1
+            kind = flags >> 4
+            has_inv = invals != 0
+            ind = ((invals[:, None] >> np.arange(nb)) & 1).astype(np.int64)
+            cum_excl = np.cumsum(ind, axis=0) - ind + inflight[None, :]
+            q = np.where(ind > 0, cum_excl, 0).max(axis=1).astype(np.float64)
+            k_local, k_rdma, k_inval, k_tlb, k_queue, k_switch, k_s2s = kvec
+            queue_f = np.where(has_inv, k_queue * q, 0.0)
+            tlb_f = np.where(has_inv, k_tlb, 0.0)
+            inv_f = np.where(has_inv, k_inval, 0.0)
+            fetch_f = np.where(fetch, k_rdma, 0.0)
+            pure_local = hit & ~has_inv
+            lb_fetch = np.where(
+                pure_local, k_local,
+                np.where(par, np.maximum(fetch_f, inv_f + queue_f), fetch_f))
+            lb_inv = np.where(seq, inv_f, 0.0)
+            lb_tlb = np.where(par | pure_local, 0.0, tlb_f)
+            lb_queue = np.where(par | pure_local, 0.0, queue_f)
+            # Cross-shard accesses traverse the switch-to-switch link to
+            # their home pipeline — the hop rides the switch term, exactly
+            # where ShardedRack._route puts it (pure local hits never leave
+            # the blade and pay nothing).
+            cross_hop = cross & ~pure_local
+            lb_switch = np.where(pure_local, 0.0, k_switch) + np.where(
+                cross_hop, k_s2s, 0.0)
+            # Lossy-fabric retransmission charge: pure local hits never
+            # leave the blade; faults never reach this path (filtered by
+            # `keep`).  Same trailing position in the sum as
+            # LatencyBreakdown.total_us — the order is load-bearing for
+            # float-exact parity.
+            if self._fab is not None:
+                lb_retry = np.where(pure_local, 0.0, self._fab[2][gidx])
+            else:
+                lb_retry = np.zeros(len(hit))
+            total = (lb_fetch + lb_inv + lb_tlb + lb_queue + lb_switch
+                     + lb_retry)
+            if pso:
+                charged = np.where(
+                    (write == 1) & ~hit, k_switch + lb_queue, total)
+            else:
+                charged = total
 
-        def commit_latency():
-            np.add.at(clocks, thread, charged)
-            self._cross_acc += int(cross_hop.sum())
-            breakdown["fetch"] += float(lb_fetch.sum())
-            breakdown["invalidation"] += float(lb_inv.sum())
-            breakdown["tlb"] += float(lb_tlb.sum())
-            breakdown["queue"] += float(lb_queue.sum())
-            breakdown["switch"] += float(lb_switch.sum())
-            breakdown["retry"] += float(lb_retry.sum())
-            inflight[:] = inflight + ind.sum(axis=0).astype(np.int32)
-            # Per-kind latency samples: arrays per chunk, flattened to
-            # plain lists once at the end of run().
-            for code, kname in enumerate(_KINDS):
-                m = kind == code
-                if m.any():
-                    trans_lat.setdefault(kname, []).append(total[m])
-            if self._tel is not None:
-                self._commit_events(gidx, vaddr, blade, write, rt, rows,
-                                    hit, kind, invals, cross_hop, charged,
-                                    dropped_all[is_acc],
-                                    nfalse_all[is_acc],
-                                    flushed_all[is_acc],
-                                    lb_fetch, lb_inv, lb_tlb, lb_queue,
-                                    lb_switch, lb_retry, kvec)
+            def commit_latency():
+                np.add.at(clocks, thread, charged)
+                self._cross_acc += int(cross_hop.sum())
+                breakdown["fetch"] += float(lb_fetch.sum())
+                breakdown["invalidation"] += float(lb_inv.sum())
+                breakdown["tlb"] += float(lb_tlb.sum())
+                breakdown["queue"] += float(lb_queue.sum())
+                breakdown["switch"] += float(lb_switch.sum())
+                breakdown["retry"] += float(lb_retry.sum())
+                inflight[:] = inflight + ind.sum(axis=0).astype(np.int32)
+                # Per-kind latency samples: arrays per chunk, flattened to
+                # plain lists once at the end of run().
+                for code, kname in enumerate(_KINDS):
+                    m = kind == code
+                    if m.any():
+                        trans_lat.setdefault(kname, []).append(total[m])
+                if self._tel is not None:
+                    self._commit_events(gidx, vaddr, blade, write, rt, rows,
+                                        hit, kind, invals, cross_hop, charged,
+                                        dropped_all[is_acc],
+                                        nfalse_all[is_acc],
+                                        flushed_all[is_acc],
+                                        lb_fetch, lb_inv, lb_tlb, lb_queue,
+                                        lb_switch, lb_retry, kvec)
 
-        self._tick("latency_reconstruct", t0)
         if defer:
             def commit():
                 commit_state()
                 commit_latency()
             return charged, commit
-        commit_latency()
+        with spans.span("commit"):
+            commit_latency()
         return charged
 
     # ------------------------------------------------------------------ #
