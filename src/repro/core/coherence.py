@@ -85,6 +85,8 @@ class CoherenceEngine:
         # Pre-populated regions: (base, log2) set; cleared on any remote
         # transition touching the region.
         self._prepopulated: set[tuple[int, int]] = set()
+        # Windows pre-populated by the directory's bulk install.
+        self.prepop_bulk_windows = 0
 
     # ------------------------------------------------------------------ #
     # Allocation hook (§4.4 'Pre-populating cache directory entries').
@@ -95,6 +97,14 @@ class CoherenceEngine:
         step = 1 << lg
         end = base + length
         me = 1 << owner_blade
+        bases = range(align_down(base, step), end, step)
+        if d.can_bulk_install(bases):
+            # All of it fresh VA: one pass leaves what the loop below
+            # would, minus the entries the loop installs only to evict.
+            self._prepopulated.update(
+                d.bulk_install_fresh(bases, lg, MSIState.M, owner_blade, me))
+            self.prepop_bulk_windows += len(bases)
+            return
         shift = d.VA_BUCKET_LOG2
         va_high = d.va_high
         addr = base

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from itertools import repeat
 
 from repro.core.types import (
     PAGE_SHIFT,
@@ -242,6 +243,72 @@ class CacheDirectory:
         if self.telemetry is not None:
             self.telemetry.event(tev.DIR_INSTALL, base=base, log2=log2)
         return e
+
+    def can_bulk_install(self, bases: range) -> bool:
+        """Whether ``bulk_install_fresh`` may stand in for installing
+        ``bases`` (ascending, contiguous windows) one at a time: every
+        window lies at or above its VA bucket's high-water mark, and the
+        directory evicts from one global LRU with no telemetry to feed.
+        A vma lies in one blade's VA span, so in one bucket."""
+        if (not bases or self.shard_budgets is not None
+                or self.eviction != "lru" or self.telemetry is not None):
+            return False
+        bucket = bases[0] >> self.VA_BUCKET_LOG2
+        return (bases[-1] >> self.VA_BUCKET_LOG2 == bucket
+                and bases[0] >= self.va_high.get(bucket, 0))
+
+    def bulk_install_fresh(self, bases: range, log2: int, state: MSIState,
+                           owner: int, sharers: int) -> list[tuple[int, int]]:
+        """Install the fresh windows ``bases`` and leave exactly the state
+        that ``_install`` of each window, followed by setting it to
+        (``state``, ``owner``, ``sharers``), leaves — without building
+        the entries that the same pass evicts again.
+
+        Where the windows outnumber the free slots, the surplus is
+        evicted in ``pick_victim`` order: the present Invalid entries,
+        then the present entries coldest first, then the new windows in
+        install order (each left ``state`` before the next install, so
+        never an Invalid victim).  Evicted windows are queued
+        on ``pending_evictions`` as the loop queues them.  Returns the
+        keys of the windows that survive.  Requires ``can_bulk_install``.
+        """
+        assert state != MSIState.I
+        n = len(bases)
+        present = len(self.entries)
+        k = max(0, n - max(0, self.resources.max_directory_entries - present))
+        # The loop installs each window Invalid, so a window sits on the
+        # maybe-Invalid list until a victim walk that finds no Invalid
+        # entry prunes it; such a walk happens once k outnumbers the
+        # present entries still marked Invalid there.
+        invalid = (key for key in self._ilru
+                   if self.entries[key].state == MSIState.I)
+        exhausted = k > sum(1 for _ in zip(range(k), invalid))
+        k_old = min(k, present)
+        for _ in range(k_old):
+            self.evict_for_capacity()
+        k_new = k - k_old
+
+        def entries(window_bases):
+            return map(DirectoryEntry, window_bases, repeat(log2),
+                       repeat(state), repeat(sharers), repeat(owner))
+
+        self.pending_evictions.extend(entries(bases[:k_new]))
+        self.capacity_evictions += k_new
+        kept = list(zip(bases[k_new:], repeat(log2)))
+        self.entries.update(zip(kept, entries(bases[k_new:])))
+        ticks = range(self._clock + k_new + 1, self._clock + n + 1)
+        self.stats.update(zip(kept, map(RegionStats, repeat(0), repeat(0),
+                                        ticks)))
+        self._clock += n
+        self._lru.update(dict.fromkeys(kept))
+        if exhausted:
+            self._ilru.clear()
+            self._ilru[kept[-1]] = None
+        else:
+            self._ilru.update(dict.fromkeys(kept))
+        self.va_high[bases[0] >> self.VA_BUCKET_LOG2] = bases[-1] + (1 << log2)
+        self.peak_entries = max(self.peak_entries, len(self.entries))
+        return kept
 
     # ------------------------------------------------------------------ #
     # Capacity eviction (amortized O(1)).

@@ -72,7 +72,9 @@ class EmulationResult:
     # injected eviction packets), and ``h2d_bytes`` / ``d2h_bytes`` (the
     # operands shipped to, and results read back from, the wave loop and
     # both TCAM kernels).  Discarded speculative chunks count: the work
-    # was done.  See docs/OBSERVABILITY.md.
+    # was done.  ``prepop_bulk_windows`` counts the arena windows that
+    # mmap-time pre-population installed in one bulk pass.  See
+    # docs/OBSERVABILITY.md.
     counters: dict = field(default_factory=dict)
     # Multi-switch (sharded-directory) racks: how many switch shards the
     # directory was partitioned across, the per-shard access counts

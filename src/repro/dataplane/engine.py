@@ -146,7 +146,8 @@ PHASES = (
     "latency_reconstruct", "epoch_control", "speculation_overhead")
 
 #: The keys of ``EmulationResult.counters`` a run() fills.
-COUNTERS = ("waves", "wave_slots", "packets", "h2d_bytes", "d2h_bytes")
+COUNTERS = ("waves", "wave_slots", "packets", "h2d_bytes", "d2h_bytes",
+            "prepop_bulk_windows")
 
 
 # --------------------------------------------------------------------- #
@@ -396,7 +397,11 @@ class BatchedDataPlane:
             # Arena mapping happens with the directory hooks attached (as
             # in the scalar engine), so mmap-time install/evict events
             # match; everything after reconstructs events host-side.
+            coh = rack.mmu.engine
+            bulk0 = coh.prepop_bulk_windows
             segs = rack._map_arena(trace)
+            self.counters["prepop_bulk_windows"] = (
+                coh.prepop_bulk_windows - bulk0)
             self._tel = getattr(rack, "telemetry", None)
         nthreads = rack.nb * rack.tpb
         mmu = rack.mmu

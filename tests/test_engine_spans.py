@@ -5,7 +5,8 @@ with one ``mind.<phase>`` child per ``PHASES`` phase (their durations
 add up to ``phase_times``), spans for the steps between phases, and a
 ``mind.speculate`` span per speculative attempt, a discarded one holding
 a ``mind.spec_rollback``.  ``EmulationResult.counters`` counts the
-waves, lane slots, packets and host-device bytes of the device calls.
+waves, lane slots, packets and host-device bytes of the device calls,
+and the windows the arena's bulk pre-population installed.
 The spans are read with the chip benchmark's own trace reader.
 """
 
@@ -18,6 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core import traces as T
+from repro.core.directory import CacheDirectory
 from repro.core.emulator import DisaggregatedRack
 from repro.dataplane import engine as E
 from repro.kernels import ops
@@ -186,10 +188,11 @@ def test_discarded_speculation_tells_from_committed(speculative):
 
 
 def _spied(monkeypatch, make_rack, trace):
-    """Replay with every device call of the engine and every directory
-    eviction its residency pre-pass injects counted by hand."""
+    """Replay with every device call of the engine, every directory
+    eviction its residency pre-pass injects and every window the bulk
+    pre-population installs counted by hand."""
     seen = dict(waves=0, wave_slots=0, packets=0, h2d_bytes=0,
-                d2h_bytes=0, evictions=0)
+                d2h_bytes=0, prepop_bulk_windows=0, evictions=0)
 
     def ship(args, outs):
         seen["h2d_bytes"] += sum(np.asarray(a).nbytes for a in args)
@@ -224,11 +227,18 @@ def _spied(monkeypatch, make_rack, trace):
         seen["evictions"] += len(out[2])
         return out
 
+    bulk = CacheDirectory.bulk_install_fresh
+
+    def spy_bulk(self, bases, *args):
+        seen["prepop_bulk_windows"] += len(bases)
+        return bulk(self, bases, *args)
+
     monkeypatch.setattr(E, "_replay", spy_replay)
     monkeypatch.setattr(ops, "protect_check", spy_protect)
     monkeypatch.setattr(ops, "translate_lookup", spy_translate)
     monkeypatch.setattr(E.BatchedDataPlane, "_residency_prepass",
                         spy_prepass)
+    monkeypatch.setattr(CacheDirectory, "bulk_install_fresh", spy_bulk)
     return make_rack().run(trace), seen
 
 
@@ -239,6 +249,8 @@ def test_counters_by_hand(monkeypatch):
     assert seen["evictions"] > 0  # directory pressure injected packets
     assert c["packets"] == res.stats.accesses + seen["evictions"]
     assert c["wave_slots"] >= c["packets"] > 0
+    # The 4 MB store, one 16 KB window at a time, past 120 slots.
+    assert c["prepop_bulk_windows"] == (4 << 20) >> 14
     for k in E.COUNTERS:
         assert c[k] == seen[k], k
     # Each call ships at least one copy of the plane bitmaps per lane.
